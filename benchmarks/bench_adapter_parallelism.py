@@ -27,6 +27,9 @@ def ensure_artifacts() -> None:
         return
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    # the 512 devices are virtual host-CPU devices: pin the child to the
+    # CPU so it never claims an accelerator its parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     subprocess.run(
         [sys.executable, "-m", "repro.launch.sharding_variants",
          "--arch", ARCH, "--shape", SHAPE],

@@ -5,9 +5,9 @@ naturally sweeps r = 4..64 — but the zero-masked (§A.1 padded) execution
 bills every slot at r_max: a rank-4 adapter co-located with a rank-64 one
 pays 16x its true FLOPs in all six grouped GEMMs, and the §A.3 memory
 model budgets replicas as if every slot were r_max wide. The rank-local
-path makes rank a per-slot compute dimension (dead rank tiles skip the
-MXU) and the §A.3 budget rank-aware (rank-weighted FLOP-tokens at TRUE
-ranks). This bench quantifies both effects:
+path masks each slot to its true rank inside the kernels and makes the
+§A.3 budget rank-aware (rank-weighted FLOP-tokens at TRUE ranks). This
+bench quantifies both effects:
 
 1. **Cluster A/B/C (virtual time).** One long fusable host, exclusive hog
    tasks pinning the remaining GPUs, and a stream of small fusable tasks

@@ -22,6 +22,7 @@ from repro.core import lora as LORA
 from repro.data.synthetic import SlotBatcher, make_task_dataset
 from repro.launch import partitioning as PT
 from repro.launch import steps_dist
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.optim import adamw
 from repro.roofline import hlo as HLO
@@ -29,7 +30,7 @@ from repro.roofline import hlo as HLO
 
 def main() -> None:
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = dataclasses.replace(
         get_arch("paper-llama-tiny").reduced(num_layers=2, d_model=128,
                                              vocab=512), dtype="float32")

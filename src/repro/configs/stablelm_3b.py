@@ -1,7 +1,7 @@
-"""stablelm-3b — StableLM-family dense decoder.
+"""stablelm-3b — StableLM-3B-4E1T dense decoder.
 
-[hf:stabilityai/stablelm-2-1_6b] (assigned dims) 32L d_model=2560 32H
-(GQA kv=32 => MHA) d_ff=6912 vocab=50304.
+[hf:stabilityai/stablelm-3b-4e1t] 32L d_model=2560 32H (kv=32 => MHA)
+head_dim=80 d_ff=6912 vocab=50304.
 """
 from repro.configs.base import DENSE, ModelConfig, RoPEConfig
 
@@ -18,5 +18,8 @@ CONFIG = ModelConfig(
     rope=RoPEConfig(theta=10_000.0),
     long_context_mode="window",   # long_500k uses sliding-window decode
     sliding_window=8192,
-    citation="hf:stabilityai/stablelm-2-1_6b",
+    citation="hf:stabilityai/stablelm-3b-4e1t",
+    notes="departs from the published model: RMSNorm in place of "
+          "LayerNorm, and rotary on the full head_dim in place of the "
+          "published 25% partial rotary",
 )

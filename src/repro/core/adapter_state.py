@@ -181,8 +181,8 @@ class SlotManager:
     def mixed_rank(self, r_max: int) -> bool:
         """True iff some occupied slot's true rank is below r_max — the
         executor's per-step dispatch predicate for the rank-local LoRA
-        path (a homogeneous full-rank mix has no dead rank tile to skip
-        and stays on the bitwise-identical dense/ragged path)."""
+        path (a homogeneous full-rank mix has no rank to mask and stays
+        on the bitwise-identical dense/ragged path)."""
         return any(j is not None and self.slot_rank[i] < r_max
                    for i, j in enumerate(self.slot_jobs))
 

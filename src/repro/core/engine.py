@@ -50,7 +50,7 @@ class Task:
     seed: int = 0
     name: str = ""
     loss_kind: str = "sft"
-    device_memory: float = 16 * 2 ** 30   # HBM per device (v5e default)
+    device_memory: float = profiler.HBM_BYTES   # HBM per device (v5e)
 
     def model_config(self) -> ModelConfig:
         return (self.model if isinstance(self.model, ModelConfig)
@@ -221,8 +221,7 @@ class Engine:
         step times live on a different clock (`ProfileStore.
         wall_step_time`). Duration feedback flows through the store's
         realized/worst-case ratio instead. Rank-aware: the LoRA term is
-        billed at the task's true rank (rank-local kernels skip the
-        padded rank tiles), not r_max."""
+        billed at the task's true rank, not r_max."""
         cfg = task.model_config()
         jobs = task.jobs()
         bsz = max(tc.per_adapter_batch for tc in jobs.values())
@@ -281,7 +280,10 @@ class Engine:
         return sched
 
     # ---- execution ----------------------------------------------------------
-    def _base_params(self, cfg: ModelConfig, seed: int = 0) -> Dict:
+    def base_params(self, cfg: ModelConfig, seed: int = 0) -> Dict:
+        """The frozen backbone of ``cfg``, built once per engine and shared
+        by every executor it makes (and by a serving replica the caller
+        builds), so the device holds one copy."""
         if cfg.name not in self._param_cache:
             self._param_cache[cfg.name] = M.init_params(
                 jax.random.PRNGKey(seed), cfg)
@@ -294,7 +296,7 @@ class Engine:
         Z = self.pick_slots(task)
         bsz = max(tc.per_adapter_batch for tc in jobs.values())
         return BatchedExecutor(
-            cfg, self._base_params(cfg, task.seed),
+            cfg, self.base_params(cfg, task.seed),
             self._dataset(task), Z=Z, per_adapter_batch=bsz,
             ee=early_exit, eval_every=self.eval_every, seed=task.seed,
             loss_kind=task.loss_kind, mem_model=self.memory_model(task))

@@ -169,8 +169,7 @@ class SharedBackboneExecutor:
         key = jax.random.PRNGKey(seed)
         self.key, k_slots = jax.random.split(key)
         self.slots = SlotManager(cfg, Z, M.target_shapes(cfg), k_slots)
-        self._train_step = jax.jit(
-            STEPS.make_train_step(cfg, loss_kind=loss_kind))
+        self._train_step = STEPS.jit_train_step(cfg, loss_kind=loss_kind)
         self._eval_step = jax.jit(
             STEPS.make_eval_step(cfg, loss_kind=loss_kind))
         self._lifecycles: Dict[str, "TaskLifecycle"] = {}
@@ -307,7 +306,7 @@ class SharedBackboneExecutor:
                 batch["slot_rows"] = jnp.asarray(slot_rows)
             if self.slots.mixed_rank(self.cfg.lora.r_max):
                 # some resident rank < r_max: route LoRA through the
-                # rank-local kernels (dead rank tiles skip the MXU); a
+                # rank-local kernels (each slot masked to its rank); a
                 # homogeneous full-rank mix stays on the dense path,
                 # which the rank-local ops reproduce bitwise
                 batch["slot_ranks"] = self.slots.ranks
@@ -326,18 +325,31 @@ class SharedBackboneExecutor:
     def eval_task(self, lc: "TaskLifecycle") -> np.ndarray:
         """Per-slot val losses for ``lc``'s dataset (broadcast to all Z
         slots; slot isolation makes foreign-slot entries meaningless to
-        this task and identical-to-solo for its own)."""
+        this task and identical-to-solo for its own). The rows run in
+        chunks of the task's widest per-adapter batch, so an eval holds no
+        more activations than a train step at that width (and chunks the
+        same alone or co-located); each chunk's per-slot mean is weighted
+        by its scored tokens (SFT) or rows."""
         t0 = time.time()
         rows = lc.batcher.val_batch_dict()
-        batch = {k: jnp.asarray(np.broadcast_to(
-                     v[0][None], (self.Z,) + v.shape[1:]))
-                 for k, v in rows.items()}
-        if self.slots.mixed_rank(self.cfg.lora.r_max):
-            batch["slot_ranks"] = self.slots.ranks
-        val = np.asarray(self._eval_step(
-            self.params, self.slots.lora, self.slots.active, batch))
+        n = next(iter(rows.values())).shape[1]
+        chunk = max(min(max(lc.job_width(j) for j in lc.jobs), n), 1)
+        total, weight = np.zeros((self.Z,), np.float64), 0.0
+        for lo in range(0, n, chunk):
+            part = {k: v[0, lo:lo + chunk] for k, v in rows.items()}
+            batch = {k: jnp.asarray(np.broadcast_to(
+                         v[None], (self.Z,) + v.shape))
+                     for k, v in part.items()}
+            if self.slots.mixed_rank(self.cfg.lora.r_max):
+                batch["slot_ranks"] = self.slots.ranks
+            w = (float(np.sum(part["labels"] >= 0)) if "labels" in part
+                 else float(len(next(iter(part.values())))))
+            total += w * np.asarray(self._eval_step(
+                self.params, self.slots.lora, self.slots.active, batch),
+                np.float64)
+            weight += w
         self._wall += time.time() - t0
-        return val
+        return (total / max(weight, 1.0)).astype(np.float32)
 
     def take_wall(self) -> float:
         wall, self._wall = self._wall, 0.0
@@ -377,7 +389,7 @@ class TaskLifecycle:
     with other tasks (the loss-isolation property, tested in
     tests/test_lora_isolation.py). One caveat: on the PALLAS backend a
     full-rank task gains a low-rank co-tenant flips from the dense to the
-    rank-local kernels, whose rank-tiled fp32 accumulation is parity-level
+    rank-local kernels, whose rank-masked fp32 accumulation is parity-level
     (not bitwise) vs dense — the jnp path (what the engine/service jit
     today) masks with a full-rank-identity select and stays bitwise."""
 

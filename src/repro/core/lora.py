@@ -10,8 +10,8 @@ Per-slot true ranks are expressed by zeroing columns/rows beyond ``r_i``
 and receives zero gradient (B's padded rows are zero ⇒ dS pads are zero ⇒
 dA pads are zero), and the optimizer additionally re-masks after each update.
 Under a ``slot_ranks`` binding the ranks become a COMPUTE dimension instead:
-the rank-local grouped-GEMM kernels skip dead rank tiles outright and the
-re-mask is provably redundant (the padded region's gradient is exactly zero
+the rank-local grouped-GEMM kernels mask each slot's padded rank on load
+and the re-mask is provably redundant (the padded region's gradient is exactly zero
 by construction, not by cancellation).
 
 ``lora_delta`` dispatches between the pure-jnp path (the mathematical
@@ -106,8 +106,8 @@ def _apply_row_mask(x: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
 # every ``lora_delta`` inside the trace then confines slot z's compute to
 # its first ranks[z] rank rows/columns — the jnp path by masking A/B (so
 # correctness never leans on the padded region being zero), the Pallas
-# path via the rank-local grouped-GEMM kernels whose dead rank tiles skip
-# the MXU outright. Composes with ``ragged_rows``.
+# path via the rank-local grouped-GEMM kernels, which mask each slot's
+# padded rank on load. Composes with ``ragged_rows``.
 
 @contextlib.contextmanager
 def slot_ranks(ranks: Optional[jnp.ndarray]):

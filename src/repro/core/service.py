@@ -91,14 +91,19 @@ class ServiceLoop:
     def __init__(self, thread: threading.Thread, stop: threading.Event):
         self._thread = thread
         self._stop = stop
+        self.error: Optional[Exception] = None   # what crashed the pump
 
     @property
     def alive(self) -> bool:
         return self._thread.is_alive()
 
     def stop(self, timeout: Optional[float] = 10.0) -> None:
+        """Stop the pump and wait for it. Re-raises the exception that
+        crashed the pump, so a failed session never ends quietly."""
         self._stop.set()
         self._thread.join(timeout)
+        if self.error is not None:
+            raise self.error
 
 
 class TaskState(enum.Enum):
@@ -534,7 +539,8 @@ class TuningService:
         chunk boundaries as in batch driving. A stall watchdog logs a
         warning when the runtime is busy but no event has fired within
         ``stall_timeout_s`` real seconds. Returns a ``ServiceLoop``
-        handle — call ``.stop()`` to drain out."""
+        handle — call ``.stop()`` to drain out; it re-raises whatever
+        crashed the pump."""
         assert self._loop is None or not self._loop.alive, \
             "service loop already running"
         stop = threading.Event()
@@ -549,8 +555,9 @@ class TuningService:
                         more = self._step()
                         busy = not self._runtime.idle()
                         n = len(self._runtime_events())
-                except Exception:
+                except Exception as e:
                     _log.exception("service loop crashed")
+                    loop.error = e           # re-raised by loop.stop()
                     return
                 nowm = time.monotonic()
                 if n != seen:
@@ -571,9 +578,9 @@ class TuningService:
 
         t = threading.Thread(target=pump, name="tuning-service-loop",
                              daemon=True)
+        loop = self._loop = ServiceLoop(t, stop)
         t.start()
-        self._loop = ServiceLoop(t, stop)
-        return self._loop
+        return loop
 
     # ------------------------------------------------------------ recovery
     @classmethod

@@ -34,8 +34,8 @@ def make_train_step(cfg: ModelConfig, *, loss_kind: str = "sft",
     ``slot_ranks`` ([Z] int32, per-slot TRUE adapter ranks from the
     executor's SlotManager): LoRA deltas then confine each slot to its
     first ranks[z] rank rows/columns (the rank-local grouped-GEMM path —
-    dead rank tiles skip the MXU, the padded rank region gets exactly
-    zero gradient, and the post-step rank re-mask is redundant)."""
+    the padded rank region is masked on load, gets exactly zero gradient,
+    and the post-step rank re-mask is redundant)."""
     loss_fn_inner = {"sft": LS.sft_loss, "dpo": LS.dpo_loss}[loss_kind]
 
     def train_step(params, lora, opt_state, hp: adamw.SlotHParams,
@@ -66,6 +66,16 @@ def make_train_step(cfg: ModelConfig, *, loss_kind: str = "sft",
         return new_lora, new_opt, metrics
 
     return train_step
+
+
+def jit_train_step(cfg: ModelConfig, *, loss_kind: str = "sft") -> Callable:
+    """``make_train_step`` jitted with the slot LoRA tree and optimizer
+    state (args 1 and 2) donated: the update writes into their buffers,
+    so a step holds one copy of the adapter state, not the old and the new
+    side by side. Callers must drop their references to the inputs and
+    keep the returned trees."""
+    return jax.jit(make_train_step(cfg, loss_kind=loss_kind),
+                   donate_argnums=(1, 2))
 
 
 def make_eval_step(cfg: ModelConfig, *, loss_kind: str = "sft") -> Callable:

@@ -1,17 +1,14 @@
 """Tile-plan autotuning for the grouped-LoRA kernel family.
 
-The rank-local kernels shipped with guessed block constants — ``BR = 8``
-against the MXU's 128 lanes, ``BM/BN/BK/BT`` inherited from the dense
-kernels — and the ROADMAP flagged them as the remaining rank-depth thread.
-This module closes it: a ``TilePlan`` names one candidate block shape
-``(BT, BM, BN, BK, BR)``, the autotuner enumerates the sublane/MXU-legal
-candidates for a ``(d_in, d_out, r_max, Z, token-bucket)`` key, times each
-on the six rank-local kernels (fwd S=XA / Y=SB and the four bwd kernels)
-via ``profiler.measure_throughput`` (warmup + median-of-repeats, so
-winners aren't picked off compile time or timer noise), and caches the
-winner twice: in-process (like ``ops._tile_plan``) and durably through
-``ProfileStore.put_spec(..., durable=True)`` so later sessions skip the
-sweep.
+A ``TilePlan`` names one candidate block shape ``(BT, BM, BN, BK)``
+for the grouped-LoRA kernel family; the autotuner enumerates the
+Mosaic-legal candidates for a ``(d_in, d_out, r_max, Z, token-bucket)``
+key, times each on the six rank-local kernels (fwd S=XA / Y=SB and the
+four bwd kernels) via ``profiler.measure_throughput`` (warmup +
+median-of-repeats, so winners aren't picked off compile time or timer
+noise), and caches the winner twice: in-process (like ``ops._tile_plan``)
+and durably through ``ProfileStore.put_spec(..., durable=True)`` so later
+sessions skip the sweep.
 
 **The bitwise contract.** Tuned plans must produce outputs bitwise
 identical to the default constants (the executor's fused-vs-solo and
@@ -23,9 +20,9 @@ where its axis is parallel:
 
   * ``bm`` (token rows) and ``bn`` (output features) are parallel in every
     kernel they touch — freely tunable;
-  * ``br`` (rank tile) is parallel in xa / ds / da / db (rank is an OUTPUT
-    axis there) and is tuned for those four; sb / dx contract over rank,
-    so they keep the default ``ranklocal.BR`` grouping;
+  * the rank axis is never tiled: every block holds the whole padded
+    rank (Mosaic's (8, 128) rule, and every configured ``r_max`` fits one
+    128-lane MXU pass);
   * ``bk`` / ``bt`` are pure contraction blocks (d_in/d_out resp. token
     contraction) — candidates pin them to the default grouping. They stay
     in the plan so a future parity-level (TPU, non-bitwise) sweep can
@@ -37,8 +34,8 @@ non-identical candidates are discarded — so the winner is bitwise-equal by
 construction, not by hope. The default plan always competes, so the tuned
 plan is never slower than the default on the probe.
 
-interpret=True times the CPU interpret-mode harness (this container's
-hardware); on TPU the same sweep times Mosaic lowerings.
+interpret=True times the CPU interpret-mode harness; on TPU the same
+sweep times Mosaic lowerings.
 """
 from __future__ import annotations
 
@@ -55,7 +52,7 @@ from repro.kernels.grouped_lora import ranklocal as RL
 _LANE = 128   # MXU lane width: last-dim block unit
 _SUB = 8      # fp32 sublane: second-to-last-dim block unit
 
-PLAN_SPEC_VERSION = 1
+PLAN_SPEC_VERSION = 2   # 2: the rank axis is no longer a plan field
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,24 +61,22 @@ class TilePlan:
 
     Field roles (see module docstring for the bitwise rationale):
     ``bm`` token-row block, ``bn`` output-feature block, ``bk`` feature
-    contraction block, ``bt`` token contraction block (weight grads),
-    ``br`` rank tile (applied where rank is an output axis)."""
+    contraction block, ``bt`` token contraction block (weight grads)."""
     bm: int = K.BM
     bn: int = K.BN
     bk: int = K.BK
     bt: int = K.BT
-    br: int = RL.BR
 
     def to_json(self) -> Dict[str, int]:
         return {"version": PLAN_SPEC_VERSION, "bm": self.bm, "bn": self.bn,
-                "bk": self.bk, "bt": self.bt, "br": self.br}
+                "bk": self.bk, "bt": self.bt}
 
     @classmethod
     def from_json(cls, d: Dict) -> Optional["TilePlan"]:
         if not isinstance(d, dict) or d.get("version") != PLAN_SPEC_VERSION:
             return None
         return cls(bm=int(d["bm"]), bn=int(d["bn"]), bk=int(d["bk"]),
-                   bt=int(d["bt"]), br=int(d["br"]))
+                   bt=int(d["bt"]))
 
 
 DEFAULT_PLAN = TilePlan()
@@ -122,14 +117,14 @@ def _divides(block: int, dim: int) -> bool:
 
 def is_legal(plan: TilePlan, tokens: int, d_in: int, d_out: int,
              r_max: int) -> bool:
-    """Sublane/MXU legality of a plan for one shape key: every field a
-    positive multiple of its axis unit (sublane 8 for token/rank axes,
-    lane 128 for feature axes) and grid-exact against the padded dims on
-    every axis it tiles (``bn``/``bk`` touch BOTH d_in and d_out)."""
-    Tp, dinp, doutp, rp = padded_dims(tokens, d_in, d_out, r_max)
-    if min(plan.bm, plan.bn, plan.bk, plan.bt, plan.br) <= 0:
+    """Mosaic legality of a plan for one shape key: every field a positive
+    multiple of its axis unit (sublane 8 for token axes, lane 128 for
+    feature axes) and grid-exact against the padded dims on every axis it
+    tiles (``bn``/``bk`` touch BOTH d_in and d_out)."""
+    Tp, dinp, doutp, _ = padded_dims(tokens, d_in, d_out, r_max)
+    if min(plan.bm, plan.bn, plan.bk, plan.bt) <= 0:
         return False
-    if plan.bm % _SUB or plan.bt % _SUB or plan.br % _SUB:
+    if plan.bm % _SUB or plan.bt % _SUB:
         return False
     if plan.bn % _LANE and plan.bn < min(dinp, doutp):
         return False
@@ -137,8 +132,7 @@ def is_legal(plan: TilePlan, tokens: int, d_in: int, d_out: int,
         return False
     return (_divides(plan.bm, Tp) and _divides(plan.bt, Tp)
             and _divides(plan.bn, dinp) and _divides(plan.bn, doutp)
-            and _divides(plan.bk, dinp) and _divides(plan.bk, doutp)
-            and _divides(plan.br, rp))
+            and _divides(plan.bk, dinp) and _divides(plan.bk, doutp))
 
 
 def _axis_choices(dim: int, unit: int, cap: int) -> List[int]:
@@ -156,14 +150,13 @@ def candidate_plans(tokens: int, d_in: int, d_out: int, r_max: int,
     """Legal candidate block shapes for one shape key.
 
     ``bm`` sweeps sublane-multiple divisors of the padded token dim,
-    ``bn`` lane-multiple divisors legal for BOTH feature dims, ``br``
-    sublane-multiple divisors of the padded rank dim. ``bk``/``bt`` are
-    pinned to the defaults (contraction grouping — the bitwise contract,
-    module docstring). The default plan is always candidate 0; the rest
-    are evenly subsampled down to ``max_candidates``."""
-    Tp, dinp, doutp, rp = padded_dims(tokens, d_in, d_out, r_max)
+    ``bn`` lane-multiple divisors legal for BOTH feature dims.
+    ``bk``/``bt`` are pinned to the defaults (contraction grouping — the
+    bitwise contract, module docstring). The default plan is always
+    candidate 0; the rest are evenly subsampled down to
+    ``max_candidates``."""
+    Tp, dinp, doutp, _ = padded_dims(tokens, d_in, d_out, r_max)
     bms = _axis_choices(Tp, _SUB, 256)
-    brs = _axis_choices(rp, _SUB, 256)
     bns = [b for b in _axis_choices(doutp, _LANE, 1024)
            if _divides(b, dinp)]
     if not bns:
@@ -171,11 +164,9 @@ def candidate_plans(tokens: int, d_in: int, d_out: int, r_max: int,
     plans: List[TilePlan] = [DEFAULT_PLAN]
     for bm in bms:
         for bn in bns:
-            for br in brs:
-                p = TilePlan(bm=bm, bn=bn, br=br)
-                if p != DEFAULT_PLAN and is_legal(p, tokens, d_in, d_out,
-                                                 r_max):
-                    plans.append(p)
+            p = TilePlan(bm=bm, bn=bn)
+            if p != DEFAULT_PLAN and is_legal(p, tokens, d_in, d_out, r_max):
+                plans.append(p)
     if len(plans) > max_candidates:
         rest = plans[1:]
         stride = len(rest) / (max_candidates - 1)
@@ -190,8 +181,8 @@ def candidate_plans(tokens: int, d_in: int, d_out: int, r_max: int,
 
 def _probe_operands(Z: int, tokens: int, d_in: int, d_out: int, r_max: int,
                     seed: int = 0):
-    """Representative operands: mixed true ranks (so dead rank tiles and
-    boundary masks are both exercised) and a ragged row tail."""
+    """Representative operands: mixed true ranks (so the rank masks are
+    exercised) and a ragged row tail."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     x = jax.random.normal(ks[0], (Z, tokens, d_in), jnp.float32)
     A = 0.1 * jax.random.normal(ks[1], (Z, d_in, r_max), jnp.float32)
@@ -208,23 +199,21 @@ def _probe_operands(Z: int, tokens: int, d_in: int, d_out: int, r_max: int,
 
 def six_kernel_step(plan: TilePlan, interpret: bool = True):
     """A jitted function running all six rank-local kernels under one
-    plan — the autotuner's unit of timing AND of bitwise comparison.
-    ``br`` applies only where rank is an output axis (xa/ds/da/db); the
-    rank-contraction kernels (sb/dx) keep the default grouping."""
+    plan — the autotuner's unit of timing AND of bitwise comparison."""
 
     def step(x, A, B, dy, scale, rows, ranks):
-        s = RL.xa(x, A, rows, ranks, bm=plan.bm, bk=plan.bk, br=plan.br,
+        s = RL.xa(x, A, rows, ranks, bm=plan.bm, bk=plan.bk,
                   interpret=interpret)
         y = RL.sb_add(s, B, scale, rows, ranks, bm=plan.bm, bn=plan.bn,
-                      br=RL.BR, interpret=interpret)
+                      interpret=interpret)
         ds_ = RL.ds(dy, B, scale, rows, ranks, bm=plan.bm, bk=plan.bk,
-                    br=plan.br, interpret=interpret)
-        dx_ = RL.dx(ds_, A, rows, ranks, bm=plan.bm, bn=plan.bn, br=RL.BR,
+                    interpret=interpret)
+        dx_ = RL.dx(ds_, A, rows, ranks, bm=plan.bm, bn=plan.bn,
                     interpret=interpret)
         dA_ = RL.da(x, ds_, rows, ranks, bd=plan.bn, bt=plan.bt,
-                    br=plan.br, interpret=interpret)
+                    interpret=interpret)
         dB_ = RL.db(s, dy, scale, rows, ranks, bn=plan.bn, bt=plan.bt,
-                    br=plan.br, interpret=interpret)
+                    interpret=interpret)
         return s, y, ds_, dx_, dA_, dB_
 
     return jax.jit(step)

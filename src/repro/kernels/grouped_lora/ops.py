@@ -272,15 +272,12 @@ def ragged_grouped_lora(x: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
 
 def _ranklocal_fwd_impl(x, A, B, scale, ranks, rows, y_base, interpret,
                         plan=DEFAULT_PLAN):
-    # plan.br applies only where rank is an OUTPUT axis (xa; and ds/da/db
-    # below) — sb_add/dx contract over rank, so they keep the default BR
-    # grouping to preserve bitwise identity with the static constants.
     T, dout = x.shape[1], B.shape[2]
     xp, Ap, Bp, yb = _pad_fwd(x, A, B, y_base)
-    s = RL.xa(xp, Ap, rows, ranks, bm=plan.bm, bk=plan.bk, br=plan.br,
+    s = RL.xa(xp, Ap, rows, ranks, bm=plan.bm, bk=plan.bk,
               interpret=interpret)
     y = RL.sb_add(s, Bp, scale, rows, ranks, yb, bm=plan.bm, bn=plan.bn,
-                  br=RL.BR, interpret=interpret)
+                  interpret=interpret)
     return y[:, :T, :dout], s[:, :T, :]
 
 
@@ -290,13 +287,13 @@ def _ranklocal_bwd_impl(x, A, B, scale, ranks, rows, s, dy, interpret,
     r, dout = B.shape[1], B.shape[2]
     xp, Ap, Bp, sp, dyp = _pad_bwd(x, A, B, s, dy)
     ds_ = RL.ds(dyp, Bp, scale, rows, ranks, bm=plan.bm, bk=plan.bk,
-                br=plan.br, interpret=interpret)
-    dx_ = RL.dx(ds_, Ap, rows, ranks, bm=plan.bm, bn=plan.bn, br=RL.BR,
                 interpret=interpret)
-    dA_ = RL.da(xp, ds_, rows, ranks, bd=plan.bn, bt=plan.bt, br=plan.br,
+    dx_ = RL.dx(ds_, Ap, rows, ranks, bm=plan.bm, bn=plan.bn,
+                interpret=interpret)
+    dA_ = RL.da(xp, ds_, rows, ranks, bd=plan.bn, bt=plan.bt,
                 interpret=interpret)
     dB_ = RL.db(sp, dyp, scale, rows, ranks, bn=plan.bn, bt=plan.bt,
-                br=plan.br, interpret=interpret)
+                interpret=interpret)
     return (dx_[:, :T, :din], dA_[:, :din, :r], dB_[:, :r, :dout])
 
 
@@ -364,14 +361,13 @@ def ranklocal_grouped_lora(x: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
                            plan: Optional[TilePlan] = None) -> jnp.ndarray:
     """Differentiable RANK-LOCAL grouped LoRA: slot z applies only the
     first ``ranks[z]`` rank columns/rows of its adapter (and, with
-    ``rows``, only its first rows[z] token rows). Dead rank tiles skip
-    the MXU; the padded rank region gets a zero output and exactly zero
+    ``rows``, only its first rows[z] token rows). The kernels mask the
+    padded rank on load: it gets a zero output and exactly zero
     gradient, so no post-step re-mask is needed on this path.
 
     x: [Z,T,din]; A: [Z,din,r]; B: [Z,r,dout]; scale/ranks/rows: [Z].
     Concrete ``ranks`` >= r everywhere dispatch to the dense/ragged path
-    (identical tiling => bitwise-equal; rank-tiled accumulation would
-    only regroup the same fp32 sums), mirroring the executor's per-step
+    (identical tiling => bitwise-equal), mirroring the executor's per-step
     dense-vs-ragged dispatch. ``plan`` (an autotuned ``TilePlan``)
     overrides the static block constants on whichever path dispatch picks;
     tuned-vs-default outputs are bitwise identical (parallel-dim re-tiling

@@ -84,7 +84,7 @@ def ranklocal_lora_ref(x, A, B, scale, ranks, rows=None,
 def ranklocal_lora_bwd_ref(x, A, B, scale, ranks, rows, s, dy
                            ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Rank-local backward oracle: the padded rank region of dA/dB is
-    exactly zero (dead rank tiles are skipped, never accumulated) and
+    exactly zero (masked on load, never accumulated) and
     padded token rows receive zero dX."""
     if rows is not None:
         x = _rows_mask(x, rows)

@@ -2,8 +2,10 @@
 
 The §Perf analysis showed the jnp chunked scan's dominant HBM term is the
 exact-log-space pair tensor exp(L_t - L_i) k q of shape [C, C, K]
-materialized per chunk. This kernel keeps that tensor (and all chunk
-intermediates) VMEM-resident: per grid step, HBM moves only the q/k/v/logw
+materialized per chunk. This kernel keeps that tensor (built 16 query rows
+at a time) and all chunk intermediates VMEM-resident, and takes the
+cumulative log-decays as triangular fp32 matmuls (Mosaic lowers no
+cumsum): per grid step, HBM moves only the q/k/v/logw
 chunk tiles and the y output tile — bytes drop from O(S·C·K) extra per row
 to the O(S·(3K+V)) I/O floor.
 
@@ -22,6 +24,7 @@ the jnp core, validated against it in interpret mode).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -31,6 +34,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
 NEG_INF = -1e30
+_EXACT = jax.lax.Precision.HIGHEST     # fp32 passes on the MXU
+_TN = (((0,), (0,)), ((), ()))         # a.T @ b
+_PAIR_ROWS = 16                        # query rows per pair-tensor block
 
 
 def _kernel(q_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
@@ -48,36 +54,45 @@ def _kernel(q_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
     v = v_ref[0].astype(F32)
     lw = lw_ref[0].astype(F32)
 
-    L = jnp.cumsum(lw, axis=0)                    # [C,K] <= 0
-    if decay_on_query:
-        Lq = L
-    else:
-        Lq = jnp.concatenate(
-            [jnp.zeros((1, K), F32), L[:-1]], axis=0)
+    # cumulative log-decays as exact-fp32 matmuls with a triangle of ones
+    # (Mosaic has no cumsum): L inclusive [C,K] <= 0, L_end^T [K,1]
+    t = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    i = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    L = jnp.dot((t >= i).astype(F32), lw, precision=_EXACT,
+                preferred_element_type=F32)
+    Lq = L if decay_on_query else L - lw          # exclusive for RWKV
 
     # ---- state contribution (MXU): (q . e^{Lq}) @ S_prev
     S_prev = state[...]
     q_scaled = q * jnp.exp(Lq)
     y = jnp.dot(q_scaled, S_prev, preferred_element_type=F32)
 
-    # ---- intra-chunk pairs, exact log-space, fully VMEM-resident
-    t = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-    i = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    visible = (t >= i) if decay_on_query else (t > i)
-    dd = Lq[:, None, :] - L[None, :, :]           # [C,C,K]
-    dd = jnp.where(visible[..., None], dd, NEG_INF)
-    P = jnp.sum(q[:, None, :] * k[None, :, :] * jnp.exp(dd), axis=-1)
-    if use_bonus:
-        diag = jnp.sum(q * u_ref[0].astype(F32) * k, axis=-1)   # [C]
-        P = P + jnp.where(t == i, diag[None, :], 0.0)
-    y = y + jnp.dot(P, v, preferred_element_type=F32)
-    y_ref[0] = y.astype(y_ref.dtype)
+    if use_bonus:                                 # P[t,t] += sum_K q u k
+        y = y + jnp.sum(q * u_ref[0].astype(F32) * k, axis=-1,
+                        keepdims=True) * v
+
+    # ---- intra-chunk pairs, exact log-space, fully VMEM-resident: the
+    # [BQ,C,K] pair tensor of BQ query rows at a time stays a few MiB
+    bq = math.gcd(C, _PAIR_ROWS)
+    tq = jax.lax.broadcasted_iota(jnp.int32, (bq, C, K), 0)
+    ik = jax.lax.broadcasted_iota(jnp.int32, (bq, C, K), 1)
+    for r0 in range(0, C, bq):
+        visible = (tq + r0 >= ik) if decay_on_query else (tq + r0 > ik)
+        dd = Lq[r0:r0 + bq][:, None, :] - L[None, :, :]
+        dd = jnp.where(visible, dd, NEG_INF)
+        P = jnp.sum(q[r0:r0 + bq][:, None, :] * k[None, :, :]
+                    * jnp.exp(dd), axis=-1)       # [BQ,C]
+        y_rows = y[r0:r0 + bq] + jnp.dot(P, v, preferred_element_type=F32)
+        y_ref[0, r0:r0 + bq, :] = y_rows.astype(y_ref.dtype)
 
     # ---- state update
-    L_end = L[-1:, :]                             # [1,K]
+    L_end = jnp.sum(lw, axis=0, keepdims=True)    # [1,K]
+    L_end_col = jax.lax.dot_general(              # the same sums as [K,1]
+        lw, jnp.ones((C, 1), F32), _TN, precision=_EXACT,
+        preferred_element_type=F32)
     k_scaled = k * jnp.exp(L_end - L)
-    new_state = (S_prev * jnp.exp(L_end).T
-                 + jax.lax.dot_general(k_scaled, v, (((0,), (0,)), ((), ())),
+    new_state = (S_prev * jnp.exp(L_end_col)
+                 + jax.lax.dot_general(k_scaled, v, _TN,
                                        preferred_element_type=F32))
     state[...] = new_state
 
@@ -105,6 +120,10 @@ def linear_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     use_bonus = bonus is not None
     if bonus is None:
         bonus = jnp.zeros((B, K), F32)
+    # [B,1,K] so the (1,1,K) block spans the last two array dims (Mosaic
+    # rejects a (1,K) block over [B,K]: its sublane dim is neither 8-aligned
+    # nor the whole axis)
+    bonus = bonus.reshape(B, 1, K)
 
     kern = functools.partial(_kernel, decay_on_query=decay_on_query,
                              use_bonus=use_bonus)
@@ -116,7 +135,7 @@ def linear_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pl.BlockSpec((1, C, K), lambda b, c: (b, c, 0)),   # k
             pl.BlockSpec((1, C, V), lambda b, c: (b, c, 0)),   # v
             pl.BlockSpec((1, C, K), lambda b, c: (b, c, 0)),   # logw
-            pl.BlockSpec((1, K), lambda b, c: (b, 0)),         # bonus
+            pl.BlockSpec((1, 1, K), lambda b, c: (b, 0, 0)),   # bonus
             pl.BlockSpec((1, K, V), lambda b, c: (b, 0, 0)),   # state0
         ],
         out_specs=[

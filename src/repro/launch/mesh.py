@@ -25,15 +25,20 @@ MULTI_POD = MeshConfig(shape=(2, 16, 16), axes=("pod", "data", "model"))
 
 def abstract_mesh(shape: Tuple[int, ...],
                   axes: Tuple[str, ...]) -> "jax.sharding.AbstractMesh":
-    """Version-proof AbstractMesh constructor. jax <= 0.4.x takes a single
-    ``((name, size), ...)`` shape tuple; jax >= 0.5 takes positional
-    ``(axis_sizes, axis_names)``. Dry-run/spec tests go through here so a
-    toolchain bump is a one-line fix."""
+    """Device-free mesh of the given axis sizes and names (dry-run/spec
+    tests lower against it without any devices)."""
     assert len(shape) == len(axes), (shape, axes)
-    try:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
+
+
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto axes. The sharding rules of this package
+    are GSPMD annotations (``launch/partitioning.py``); jax's default
+    Explicit axes reject them at the first embedding gather."""
+    return jax.make_mesh(shape, axes,
+                         (jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -43,14 +48,14 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     assert len(devices) >= n, (
         f"need {n} devices (run under dryrun.py, which sets "
         f"--xla_force_host_platform_device_count), have {len(devices)}")
-    return jax.make_mesh(cfg.shape, cfg.axes, devices=devices[:n])
+    return make_mesh(cfg.shape, cfg.axes, devices=devices[:n])
 
 
 def make_local_mesh(shape: Tuple[int, ...] = (1, 1),
                     axes: Tuple[str, ...] = ("data", "model")
                     ) -> jax.sharding.Mesh:
     """Tiny mesh over however many devices exist (tests/examples)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def mesh_config(mesh: jax.sharding.Mesh) -> MeshConfig:

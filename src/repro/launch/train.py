@@ -26,14 +26,68 @@ from repro.core import lora as LORA
 from repro.data.synthetic import SlotBatcher, make_task_dataset
 from repro.launch import partitioning as PT
 from repro.launch import steps_dist
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.optim import adamw
 
 
 def build_mesh(spec: str) -> jax.sharding.Mesh:
     d, m = (int(x) for x in spec.split("x"))
-    return jax.make_mesh((d, m), ("data", "model"),
-                         devices=jax.devices()[:d * m])
+    return make_mesh((d, m), ("data", "model"), jax.devices()[:d * m])
+
+
+class AdapterParallelRun:
+    """One adapter-parallel training run on ``mesh``.
+
+    The frozen backbone, the Z slot adapters (one per entry of ``ranks``),
+    their optimizer state and per-slot hyperparameters are created in
+    their production shardings — each device computes only its own share
+    — and ``step`` runs the jitted train step with the adapter state
+    donated. ``lora`` (a [L, Z, ...] slot tree) replaces the seeded
+    adapter init, so runs on different meshes can start from the same
+    adapters."""
+
+    def __init__(self, cfg, mesh: jax.sharding.Mesh, ranks, *,
+                 lr: float = 1e-3, seed: int = 0, lora=None):
+        Z = len(ranks)
+        self.mesh = mesh
+        self.ranks = jnp.asarray([min(r, cfg.lora.r_max) for r in ranks],
+                                 jnp.int32)
+        key = jax.random.PRNGKey(seed)
+
+        def make(key, lora):
+            params = M.init_params(key, cfg)
+            if lora is None:
+                lora = LORA.init_lora_tree(key, cfg, Z, self.ranks,
+                                           M.target_shapes(cfg))
+            return params, lora, adamw.init_state(lora, Z)
+
+        p, l, o = jax.eval_shape(make, key, lora)
+        ns = lambda t: PT.to_named(mesh, t)
+        p_sh = ns(PT.base_param_specs(mesh, p))
+        l_sh = ns(PT.lora_param_specs(mesh, l))
+        o_sh = ns(PT.opt_state_specs(mesh, o))
+        self.params, self.lora, self.opt = jax.jit(
+            make, out_shardings=(p_sh, l_sh, o_sh))(key, lora)
+        hp = adamw.SlotHParams.broadcast(Z, lr=lr)
+        self.hp = jax.device_put(hp, ns(PT.hp_specs(mesh, hp)))
+        v_sh = PT.to_named(mesh, PT.pick_spec(mesh, (Z,), [{0: "data"}, {}]))
+        self.active = jax.device_put(jnp.ones((Z,), jnp.int32), v_sh)
+        self.ranks = jax.device_put(self.ranks, v_sh)
+        self._step = jax.jit(steps_dist.make_train_step(cfg, mesh),
+                             out_shardings=(l_sh, o_sh, None),
+                             donate_argnums=(1, 2))
+
+    def step(self, tokens, labels) -> dict:
+        """One fused step on a [Z, b, S] batch; returns its metrics."""
+        batch = {"tokens": jnp.asarray(tokens), "labels": jnp.asarray(labels)}
+        batch = jax.device_put(
+            batch, PT.to_named(self.mesh, PT.batch_specs(self.mesh, batch)))
+        with self.mesh:
+            self.lora, self.opt, metrics = self._step(
+                self.params, self.lora, self.opt, self.hp, self.active,
+                self.ranks, batch)
+        return metrics
 
 
 def main() -> None:
@@ -61,47 +115,17 @@ def main() -> None:
     print(f"arch={cfg.name} Z={Z} b={b} S={S} "
           f"mesh={dict(mesh.shape)} devices={len(jax.devices())}")
 
-    key = jax.random.PRNGKey(0)
-    params = M.init_params(key, cfg)
-    ranks = jnp.full((Z,), min(args.rank, cfg.lora.r_max), jnp.int32)
-    lora = LORA.init_lora_tree(key, cfg, Z, ranks, M.target_shapes(cfg))
-    opt = adamw.init_state(lora, Z)
-    hp = adamw.SlotHParams.broadcast(Z, lr=args.lr)
-    active = jnp.ones((Z,), jnp.int32)
-
-    ns = lambda t: PT.to_named(mesh, t)
-    p_sh = ns(PT.base_param_specs(mesh, params))
-    l_sh = ns(PT.lora_param_specs(mesh, lora))
-    o_sh = ns(PT.opt_state_specs(mesh, opt))
-    h_sh = ns(PT.hp_specs(mesh, hp))
-    v_sh = PT.to_named(mesh, PT.pick_spec(mesh, (Z,), [{0: "data"}, {}]))
-
+    run = AdapterParallelRun(cfg, mesh, [args.rank] * Z, lr=args.lr)
     ds = make_task_dataset("launch", cfg.vocab_size, seq_len=S,
                            num_train=max(4 * Z * b, 64), difficulty=0.3)
     batcher = SlotBatcher(ds, Z, b)
-
-    tokens, labels = batcher.next_batch()
-    batch = {"tokens": jnp.asarray(tokens), "labels": jnp.asarray(labels)}
-    b_sh = ns(PT.batch_specs(mesh, batch))
-    step = jax.jit(steps_dist.make_train_step(cfg, mesh),
-                   in_shardings=(p_sh, l_sh, o_sh, h_sh, v_sh, v_sh, b_sh),
-                   out_shardings=(l_sh, o_sh, None))
-    params = jax.device_put(params, p_sh)
-    lora = jax.device_put(lora, l_sh)
-    opt = jax.device_put(opt, o_sh)
-
-    with mesh:
-        for t in range(args.steps):
-            tokens, labels = batcher.next_batch()
-            batch = {"tokens": jnp.asarray(tokens),
-                     "labels": jnp.asarray(labels)}
-            t0 = time.time()
-            lora, opt, metrics = step(params, lora, opt, hp, active,
-                                      ranks, batch)
-            jax.block_until_ready(metrics["per_slot_loss"])
-            loss = np.asarray(metrics["per_slot_loss"])
-            print(f"step {t:4d}  {time.time() - t0:6.2f}s  "
-                  f"loss/slot: {np.array2string(loss, precision=3)}")
+    for t in range(args.steps):
+        tokens, labels = batcher.next_batch()
+        t0 = time.time()
+        metrics = run.step(tokens, labels)
+        loss = np.asarray(metrics["per_slot_loss"])
+        print(f"step {t:4d}  {time.time() - t0:6.2f}s  "
+              f"loss/slot: {np.array2string(loss, precision=3)}")
     print("done")
 
 

@@ -28,11 +28,15 @@ RING_INIT_POS = -(1 << 30)    # ring-cache slots start far in the past
 # ---------------------------------------------------------------------------
 
 def init_params(key: jax.Array, cfg: ModelConfig) -> Dict:
+    """Frozen backbone params, layers stacked on a leading L axis. The
+    layer init is vmapped over the layer keys (bitwise equal to one init
+    per key), so the device never holds the per-layer trees beside their
+    stack — 2x the backbone at published widths."""
     dtype = dtype_of(cfg.dtype)
     k_emb, k_layers, k_head = jax.random.split(key, 3)
     layer_keys = jax.random.split(k_layers, cfg.num_layers)
-    layers = [B.init_layer_params(k, cfg, dtype) for k in layer_keys]
-    stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *layers)
+    stacked = jax.vmap(lambda k: B.init_layer_params(k, cfg, dtype))(
+        layer_keys)
     params = {
         "embed": normal_init(k_emb, (cfg.vocab_size, cfg.d_model),
                              0.02, dtype),

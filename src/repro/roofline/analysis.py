@@ -97,7 +97,7 @@ def model_flops(cfg: ModelConfig, shape: ShapeConfig,
 @dataclasses.dataclass
 class RankLocalSavings:
     """Adapter-GEMM FLOP/byte accounting for one slot stack, true-rank
-    (rank-local kernels: dead rank tiles skip) vs r_max-padded (the
+    (the work a slot's true rank needs) vs r_max-padded (the
     historical zero-masked execution, every slot billed at r_max).
 
     FLOPs: 6 * N_lora(r) * tokens per slot (fwd XA/SB + bwd dS/dX/dA/dB).

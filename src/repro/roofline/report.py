@@ -25,9 +25,8 @@ RANK_SWEEP = (4, 8, 16, 32, 64)
 
 def print_ranklocal(archs: List[str], tokens_per_slot: int = 4096,
                     md: bool = False) -> None:
-    """Rank-local FLOP/byte savings per config: the adapter-GEMM work the
-    dead rank-tile skip reclaims vs r_max-padded execution on the
-    rank-sweep mix, and the arithmetic-intensity shift that comes with
+    """Rank-local FLOP/byte savings per config: the adapter-GEMM work at
+    true rank vs r_max-padded execution on the rank-sweep mix, and the arithmetic-intensity shift that comes with
     it."""
     from repro.configs.registry import get_arch
     rows = [ranklocal_savings(get_arch(a), RANK_SWEEP, tokens_per_slot)

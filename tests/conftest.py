@@ -25,31 +25,6 @@ except ModuleNotFoundError:
     sys.modules["hypothesis.strategies"] = _stub.strategies
 
 
-def _has_tpu() -> bool:
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except RuntimeError:
-        return False
-
-
-def pytest_configure(config):
-    config.addinivalue_line(
-        "markers", "slow: long-running test (deselect with -m 'not slow')")
-    config.addinivalue_line(
-        "markers", "tpu: needs real TPU hardware (Pallas non-interpret "
-        "paths); auto-skipped on CPU-only runners")
-
-
-def pytest_collection_modifyitems(config, items):
-    if _has_tpu():
-        return
-    skip_tpu = pytest.mark.skip(
-        reason="no TPU: Pallas non-interpret paths run interpret-mode only")
-    for item in items:
-        if "tpu" in item.keywords:
-            item.add_marker(skip_tpu)
-
-
 @pytest.fixture(scope="session")
 def tiny_cfg():
     from repro.configs.registry import get_arch

@@ -33,16 +33,16 @@ def _operands(seed=0):
 # ---------------------------------------------------------------------------
 
 def test_candidates_are_sublane_mxu_legal():
-    Tp, dinp, doutp, rp = AT.padded_dims(T, DIN, DOUT, RMAX)
+    Tp, dinp, doutp, _ = AT.padded_dims(T, DIN, DOUT, RMAX)
     plans = AT.candidate_plans(T, DIN, DOUT, RMAX, max_candidates=64)
     assert plans[0] == AT.DEFAULT_PLAN
     assert len(plans) > 1, "no non-default candidates for this shape"
     for p in plans[1:]:
         assert AT.is_legal(p, T, DIN, DOUT, RMAX), p
-        # sublane units on token/rank axes
-        assert p.bm % 8 == 0 and p.bt % 8 == 0 and p.br % 8 == 0, p
+        # sublane units on token axes; the rank axis is never tiled
+        assert p.bm % 8 == 0 and p.bt % 8 == 0, p
         # grid-exact: a block below a dim it tiles must divide it
-        for block, dim in ((p.bm, Tp), (p.bt, Tp), (p.br, rp),
+        for block, dim in ((p.bm, Tp), (p.bt, Tp),
                            (p.bn, dinp), (p.bn, doutp),
                            (p.bk, dinp), (p.bk, doutp)):
             assert block >= dim or dim % block == 0, (p, block, dim)
@@ -57,7 +57,7 @@ def test_candidates_pin_contraction_blocks():
 
 def test_illegal_plans_rejected():
     bad = [AT.TilePlan(bm=12),                 # not a sublane multiple
-           AT.TilePlan(br=4),                  # not a sublane multiple
+           AT.TilePlan(bt=4),                  # not a sublane multiple
            AT.TilePlan(bm=0),                  # non-positive
            AT.TilePlan(bm=16)]                 # 16 < Tp=24 and 24 % 16 != 0
     for p in bad:

@@ -8,7 +8,8 @@ import pytest
 from repro.core import engine as alto
 from repro.core.adapter_state import SlotManager
 from repro.core.early_exit import EarlyExitConfig
-from repro.core.executor import BatchedExecutor
+from repro.core.executor import (BatchedExecutor, SharedBackboneExecutor,
+                                 TaskLifecycle)
 from repro.configs.base import TrainConfig
 from repro.data.synthetic import SlotBatcher, make_task_dataset
 from repro.models import model as M
@@ -40,6 +41,32 @@ def test_slot_snapshot_restore_bit_exact(env):
     for t in before:
         np.testing.assert_array_equal(before[t]["A"], after[t]["A"])
         np.testing.assert_array_equal(before[t]["B"], after[t]["B"])
+
+
+def test_eval_runs_in_chunks_of_the_task_width(env):
+    """eval_task runs the validation rows in chunks of the task's widest
+    per-adapter batch (an eval holds no more than a train step); the
+    token-weighted mean of the chunks is the one-batch eval."""
+    cfg, ds, params = env
+    ex = SharedBackboneExecutor(cfg, params, Z=2, per_adapter_batch=4)
+    lc = TaskLifecycle(ex, "t", {"j": TrainConfig(lora_rank=4,
+                                                  per_adapter_batch=4)},
+                       total_steps=4, dataset=ds)
+    ex.slots.lora = {t: {"A": ab["A"], "B": 0.1 * jax.random.normal(
+                         jax.random.PRNGKey(3), ab["B"].shape)}
+                     for t, ab in ex.slots.lora.items()}
+    step, shapes = ex._eval_step, []
+
+    def spy(*args):
+        shapes.append(args[-1]["tokens"].shape)
+        return step(*args)
+
+    ex._eval_step = spy
+    got = ex.eval_task(lc)
+    rows = {k: jnp.asarray(v) for k, v in lc.batcher.val_batch_dict().items()}
+    whole = np.asarray(step(params, ex.slots.lora, ex.slots.active, rows))
+    assert shapes == [(2, 4, 32)] * 4           # 16 rows, 4 per chunk
+    np.testing.assert_allclose(got, whole, rtol=1e-5)
 
 
 def test_executor_full_lifecycle(env):

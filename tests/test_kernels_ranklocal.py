@@ -1,7 +1,7 @@
 """Rank-local grouped-LoRA kernel parity vs the masked-jnp oracle.
 
-The rank-local path (per-slot TRUE ranks as a compute dimension; dead
-rank tiles skip the MXU) must be EXACT: the padded rank region
+The rank-local path (per-slot TRUE ranks masked inside the kernels)
+must be EXACT: the padded rank region
 contributes nothing to any output and receives exactly zero gradient —
 even when it holds garbage — and concrete full-rank calls reproduce the
 dense kernels bitwise. Interpret mode on CPU is the CI harness.
@@ -141,9 +141,9 @@ def test_full_rank_bitwise_equal_dense():
 
 
 def test_rank_one_degenerate():
-    """rank-1 slots: the narrowest possible adapter — one rank tile,
-    masked to a single column — must match the oracle and leave columns
-    >= 1 at exactly zero gradient."""
+    """rank-1 slots: the narrowest possible adapter, masked to a single
+    column — must match the oracle and leave columns >= 1 at exactly
+    zero gradient."""
     Z, T, din, r, dout = 2, 40, 64, 8, 48
     x, A, B, scale, yb = make(Z, T, din, r, dout)
     ranks = jnp.asarray([1, 1], jnp.int32)

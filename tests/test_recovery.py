@@ -403,6 +403,26 @@ def test_run_forever_drains_submissions():
     assert not loop.alive
 
 
+def test_run_forever_stop_reraises_pump_crash():
+    """A crash inside the pump ends the loop, and ``stop()`` hands the
+    exception to the caller instead of leaving it in a log line."""
+    import time as _time
+    svc = TuningService(total_gpus=4)
+    loop = svc.run_forever(poll_s=0.01)
+    spec = sim_task_spec("boom", K=4, Z=2, total_steps=20, warmup_steps=2,
+                         step_time_s=0.01, gpus=2)
+
+    def factory():
+        raise RuntimeError("driver failed to start")
+    svc.submit_spec(spec, factory)
+    deadline = _time.monotonic() + 30.0
+    while loop.alive and _time.monotonic() < deadline:
+        _time.sleep(0.01)
+    assert not loop.alive
+    with pytest.raises(RuntimeError, match="driver failed to start"):
+        loop.stop()
+
+
 def test_profile_store_corrupt_file_falls_back(tmp_path):
     from repro.sched.profiler import ProfileStore
     p = str(tmp_path / "prof.json")
