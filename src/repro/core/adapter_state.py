@@ -15,6 +15,10 @@ streams, monitors, init keys) this is the primitive that makes slot-level
 preemption and cross-replica migration invisible to the loss trajectory:
 a migrated job's subsequent losses are bitwise identical to never moving
 (tests/test_lora_isolation.py).
+
+Each slot write and snapshot is a ``TraceAnnotation`` span (``tune.admit``,
+``tune.restore``, ``tune.evict``, ``tune.snapshot``) on the profiler's
+clock; a snapshot's span counts the bytes it copies to the host.
 """
 from __future__ import annotations
 
@@ -24,6 +28,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core import lora as LORA
@@ -99,13 +104,14 @@ class SlotManager:
         and the job's own (b, seq) width."""
         assert self.slot_jobs[slot] is None, f"slot {slot} occupied"
         rank = min(tc.lora_rank, self.cfg.lora.r_max)
-        one = LORA.init_lora_tree(
-            key, self.cfg, 1, jnp.array([rank]), self.target_shapes)
-        sub = jax.tree_util.tree_map(lambda x: x[:, 0], one)
-        self.lora = _i_slot(self.lora, slot, sub)
-        self.opt_state = adamw.reset_slot(self.opt_state, slot)
-        self.ranks = self.ranks.at[slot].set(rank)
-        self.active = self.active.at[slot].set(1)
+        with TraceAnnotation("tune.admit"):
+            one = LORA.init_lora_tree(
+                key, self.cfg, 1, jnp.array([rank]), self.target_shapes)
+            sub = jax.tree_util.tree_map(lambda x: x[:, 0], one)
+            self.lora = _i_slot(self.lora, slot, sub)
+            self.opt_state = adamw.reset_slot(self.opt_state, slot)
+            self.ranks = self.ranks.at[slot].set(rank)
+            self.active = self.active.at[slot].set(1)
         self.hp = self.hp.replace_slot(
             slot, lr=tc.learning_rate, wd=tc.weight_decay,
             beta1=tc.beta1, beta2=tc.beta2, grad_clip=tc.grad_clip)
@@ -120,13 +126,14 @@ class SlotManager:
         """Rotate a snapshotted job back in (bit-exact continuation,
         including its slot width)."""
         assert self.slot_jobs[slot] is None, f"slot {slot} occupied"
-        self.lora = _i_slot(self.lora, slot, snap.lora)
-        mu = _i_slot(self.opt_state.mu, slot, snap.mu)
-        nu = _i_slot(self.opt_state.nu, slot, snap.nu)
-        cnt = self.opt_state.count.at[slot].set(snap.count)
-        self.opt_state = adamw.AdamWState(mu, nu, cnt)
-        self.ranks = self.ranks.at[slot].set(snap.rank)
-        self.active = self.active.at[slot].set(1)
+        with TraceAnnotation("tune.restore"):
+            self.lora = _i_slot(self.lora, slot, snap.lora)
+            mu = _i_slot(self.opt_state.mu, slot, snap.mu)
+            nu = _i_slot(self.opt_state.nu, slot, snap.nu)
+            cnt = self.opt_state.count.at[slot].set(snap.count)
+            self.opt_state = adamw.AdamWState(mu, nu, cnt)
+            self.ranks = self.ranks.at[slot].set(snap.rank)
+            self.active = self.active.at[slot].set(1)
         self.hp = self.hp.replace_slot(
             slot, lr=tc.learning_rate, wd=tc.weight_decay,
             beta1=tc.beta1, beta2=tc.beta2, grad_clip=tc.grad_clip)
@@ -140,24 +147,30 @@ class SlotManager:
     def snapshot(self, slot: int) -> SlotSnapshot:
         job_id = self.slot_jobs[slot]
         assert job_id is not None
-        return SlotSnapshot(
-            job_id=job_id,
-            lora=_x_slot(self.lora, slot),
-            mu=_x_slot(self.opt_state.mu, slot),
-            nu=_x_slot(self.opt_state.nu, slot),
-            count=int(self.opt_state.count[slot]),
-            rank=int(self.ranks[slot]),
-            per_adapter_batch=self.slot_b[slot],
-            seq_len=self.slot_seq[slot],
-        )
+        with TraceAnnotation("tune.snapshot") as sp:
+            snap = SlotSnapshot(
+                job_id=job_id,
+                lora=_x_slot(self.lora, slot),
+                mu=_x_slot(self.opt_state.mu, slot),
+                nu=_x_slot(self.opt_state.nu, slot),
+                count=int(self.opt_state.count[slot]),
+                rank=int(self.ranks[slot]),
+                per_adapter_batch=self.slot_b[slot],
+                seq_len=self.slot_seq[slot],
+            )
+            sp.set_metadata(d2h_bytes=sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(
+                    (snap.lora, snap.mu, snap.nu))))
+        return snap
 
     def evict(self, slot: int) -> None:
         """Drop a job: zero params + moments, deactivate (paper §5.2:
         'evicted adapters' parameters and optimizer states are discarded')."""
-        self.lora = LORA.zero_slot(self.lora, slot)
-        self.opt_state = adamw.reset_slot(self.opt_state, slot)
-        self.active = self.active.at[slot].set(0)
-        self.ranks = self.ranks.at[slot].set(0)
+        with TraceAnnotation("tune.evict"):
+            self.lora = LORA.zero_slot(self.lora, slot)
+            self.opt_state = adamw.reset_slot(self.opt_state, slot)
+            self.active = self.active.at[slot].set(0)
+            self.ranks = self.ranks.at[slot].set(0)
         self.slot_jobs[slot] = None
         self.slot_tasks[slot] = None
         self.slot_b[slot] = 0

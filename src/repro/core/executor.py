@@ -59,6 +59,11 @@ Lifecycle (unchanged from the paper):
      best-val adapter; freed slots are BACKFILLED from the pending queue
      via the §A.3 admission policy (memory-model token budget; ragged
      slots need no width matching — ``sched/intra_task.py``).
+
+The host work between device calls is marked with ``TraceAnnotation``
+spans named ``tune.*``, on the profiler's clock. Their counts are numbers
+the host already holds (token counts, byte sizes from shapes): a span
+never reads a device value, so it adds no wait.
 """
 from __future__ import annotations
 
@@ -69,6 +74,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core import steps as STEPS
@@ -301,9 +307,13 @@ class SharedBackboneExecutor:
         batch into the ragged grouped-GEMM kernels."""
         t0 = time.time()
         for _ in range(n):
-            batch, slot_rows, dense, tokens = self._assemble()
-            if not dense:
-                batch["slot_rows"] = jnp.asarray(slot_rows)
+            with TraceAnnotation("tune.assemble") as sp:
+                batch, slot_rows, dense, tokens = self._assemble()
+                if not dense:
+                    batch["slot_rows"] = jnp.asarray(slot_rows)
+                sp.set_metadata(
+                    real_tokens=tokens,
+                    positions=self.Z * self.b_cap * self._resolved_seq_cap())
             if self.slots.mixed_rank(self.cfg.lora.r_max):
                 # some resident rank < r_max: route LoRA through the
                 # rank-local kernels (each slot masked to its rank); a
@@ -314,10 +324,13 @@ class SharedBackboneExecutor:
                 self.params, self.slots.lora, self.slots.opt_state,
                 self.slots.hp, self.slots.active, self.slots.ranks, batch)
             self._tokens += tokens
-            per_loss = np.asarray(metrics["per_slot_loss"])
-            for lc in self.resident_tasks():
-                for job, (_, slot) in lc.resident.items():
-                    lc.observe_train(job, float(per_loss[slot]))
+            loss = metrics["per_slot_loss"]
+            with TraceAnnotation("tune.loss_fetch", d2h_bytes=loss.nbytes):
+                per_loss = np.asarray(loss)
+            with TraceAnnotation("tune.observe"):
+                for lc in self.resident_tasks():
+                    for job, (_, slot) in lc.resident.items():
+                        lc.observe_train(job, float(per_loss[slot]))
         # accumulate actual train/eval host time only — flush-to-flush
         # deltas would also bill time the coordinator spent suspended
         self._wall += time.time() - t0
@@ -331,23 +344,26 @@ class SharedBackboneExecutor:
         same alone or co-located); each chunk's per-slot mean is weighted
         by its scored tokens (SFT) or rows."""
         t0 = time.time()
-        rows = lc.batcher.val_batch_dict()
-        n = next(iter(rows.values())).shape[1]
-        chunk = max(min(max(lc.job_width(j) for j in lc.jobs), n), 1)
-        total, weight = np.zeros((self.Z,), np.float64), 0.0
-        for lo in range(0, n, chunk):
-            part = {k: v[0, lo:lo + chunk] for k, v in rows.items()}
-            batch = {k: jnp.asarray(np.broadcast_to(
-                         v[None], (self.Z,) + v.shape))
-                     for k, v in part.items()}
-            if self.slots.mixed_rank(self.cfg.lora.r_max):
-                batch["slot_ranks"] = self.slots.ranks
-            w = (float(np.sum(part["labels"] >= 0)) if "labels" in part
-                 else float(len(next(iter(part.values())))))
-            total += w * np.asarray(self._eval_step(
-                self.params, self.slots.lora, self.slots.active, batch),
-                np.float64)
-            weight += w
+        with TraceAnnotation("tune.eval"):
+            rows = lc.batcher.val_batch_dict()
+            n = next(iter(rows.values())).shape[1]
+            chunk = max(min(max(lc.job_width(j) for j in lc.jobs), n), 1)
+            total, weight = np.zeros((self.Z,), np.float64), 0.0
+            for lo in range(0, n, chunk):
+                part = {k: v[0, lo:lo + chunk] for k, v in rows.items()}
+                batch = {k: jnp.asarray(np.broadcast_to(
+                             v[None], (self.Z,) + v.shape))
+                         for k, v in part.items()}
+                if self.slots.mixed_rank(self.cfg.lora.r_max):
+                    batch["slot_ranks"] = self.slots.ranks
+                w = (float(np.sum(part["labels"] >= 0)) if "labels" in part
+                     else float(len(next(iter(part.values())))))
+                with TraceAnnotation("tune.eval_fetch") as fetch:
+                    out = self._eval_step(self.params, self.slots.lora,
+                                          self.slots.active, batch)
+                    fetch.set_metadata(d2h_bytes=out.nbytes)
+                    total += w * np.asarray(out, np.float64)
+                weight += w
         self._wall += time.time() - t0
         return (total / max(weight, 1.0)).astype(np.float32)
 
@@ -691,30 +707,32 @@ class TaskLifecycle:
             self._select_and_continue()
 
     def _select_and_continue(self) -> None:
-        # Pattern-3 selection at the warmup boundary (underperformance)
-        kept, dropped = warmup_select(self.monitors, self.ee,
-                                      num_candidates=self.K)
-        for j in dropped:
-            self.monitors[j]._exit(ExitReason.UNDERPERFORMING,
-                                   self.steps_done.get(j, self.warmup_steps))
-            self.snapshots.pop(j, None)
-        if dropped:
-            self._events.append(ProgressEvent(
-                kind=EventKind.WARMUP_SELECTION, task=self.task_name,
-                reason=ExitReason.UNDERPERFORMING.value,
-                step=self.warmup_steps, dropped=tuple(dropped)))
-        self.phase = "continue"
-        self._cont_step = 0
-        self._queue = list(kept)
-        # §A.3 greedy decreasing-batch-size initial admission (stable sort:
-        # a homogeneous-batch queue keeps its val-loss ranking)
-        pending = [PendingJob(j, self.job_width(j), self.job_rank(j))
-                   for j in self._queue]
-        for pj in self._policy.admit_initial(pending):
-            self._policy.evict(pj.job_id)            # _admit_job re-adds
-            self._queue.remove(pj.job_id)
-            self._admit_job(pj.job_id)
-        self._settle_continue()
+        with TraceAnnotation("tune.decide"):
+            # Pattern-3 selection at the warmup boundary (underperformance)
+            kept, dropped = warmup_select(self.monitors, self.ee,
+                                          num_candidates=self.K)
+            for j in dropped:
+                self.monitors[j]._exit(
+                    ExitReason.UNDERPERFORMING,
+                    self.steps_done.get(j, self.warmup_steps))
+                self.snapshots.pop(j, None)
+            if dropped:
+                self._events.append(ProgressEvent(
+                    kind=EventKind.WARMUP_SELECTION, task=self.task_name,
+                    reason=ExitReason.UNDERPERFORMING.value,
+                    step=self.warmup_steps, dropped=tuple(dropped)))
+            self.phase = "continue"
+            self._cont_step = 0
+            self._queue = list(kept)
+            # §A.3 greedy decreasing-batch-size initial admission (stable
+            # sort: a homogeneous-batch queue keeps its val-loss ranking)
+            pending = [PendingJob(j, self.job_width(j), self.job_rank(j))
+                       for j in self._queue]
+            for pj in self._policy.admit_initial(pending):
+                self._policy.evict(pj.job_id)        # _admit_job re-adds
+                self._queue.remove(pj.job_id)
+                self._admit_job(pj.job_id)
+            self._settle_continue()
 
     # ---- continue ----------------------------------------------------------
     def _backfill(self) -> None:
@@ -723,14 +741,15 @@ class TaskLifecycle:
         that fits the token budget co-trains in the fused step)."""
         if not self._queue or not self._free_lanes:
             return
-        pending = [PendingJob(j, self.job_width(j), self.job_rank(j))
-                   for j in self._queue]
-        pick = self._policy.backfill(pending)
-        if pick is None:
-            return
-        self._policy.evict(pick.job_id)              # _admit_job re-adds
-        self._queue.remove(pick.job_id)
-        self._admit_job(pick.job_id)
+        with TraceAnnotation("tune.decide"):
+            pending = [PendingJob(j, self.job_width(j), self.job_rank(j))
+                       for j in self._queue]
+            pick = self._policy.backfill(pending)
+            if pick is None:
+                return
+            self._policy.evict(pick.job_id)          # _admit_job re-adds
+            self._queue.remove(pick.job_id)
+            self._admit_job(pick.job_id)
 
     def _exit_job(self, job_id: str, decision: ExitDecision) -> None:
         self._events.append(ProgressEvent(
@@ -744,37 +763,44 @@ class TaskLifecycle:
         if not self.resident:
             return
         val = self.ex.eval_task(self)
-        for job_id, (_, slot) in list(self.resident.items()):
-            mon = self.monitors[job_id]
-            prev_best = mon.best_val
-            decision = mon.observe_val(float(val[slot]),
-                                       self.steps_done.get(job_id, 0))
-            # checkpoint best-val adapter (cheap: host copy of one slot)
-            if mon.val_hist[-1] <= prev_best:
-                self._best_ckpt[job_id] = self.ex.adapter_at(slot)
-            if decision is not None:
-                self._exit_job(job_id, decision)
+        with TraceAnnotation("tune.decide"):
+            for job_id, (_, slot) in list(self.resident.items()):
+                mon = self.monitors[job_id]
+                prev_best = mon.best_val
+                decision = mon.observe_val(float(val[slot]),
+                                           self.steps_done.get(job_id, 0))
+                # checkpoint best-val adapter (a host copy of one slot)
+                if mon.val_hist[-1] <= prev_best:
+                    with TraceAnnotation("tune.best_ckpt") as ck:
+                        best = self.ex.adapter_at(slot)
+                        ck.set_metadata(d2h_bytes=sum(
+                            x.nbytes
+                            for x in jax.tree_util.tree_leaves(best)))
+                    self._best_ckpt[job_id] = best
+                if decision is not None:
+                    self._exit_job(job_id, decision)
 
     def _settle_continue(self) -> None:
         """Complete at-budget jobs (possibly newly backfilled ones, who may
         arrive already at budget when warmup == total budget) and finish
         the task once queue + slots drain."""
-        changed = True
-        while changed:
-            changed = False
-            for job_id in list(self.resident):
-                if self.steps_done.get(job_id, 0) >= self.total_steps:
-                    self.monitors[job_id]._exit(
-                        ExitReason.COMPLETED, self.steps_done[job_id])
-                    self._events.append(ProgressEvent(
-                        kind=EventKind.JOB_EXITED, task=self.task_name,
-                        job=job_id, reason=ExitReason.COMPLETED.value,
-                        step=self.steps_done[job_id]))
-                    self._evict_job(job_id)
-                    self._backfill()
-                    changed = True
-        if not self.resident and not self._queue:
-            self._finish()
+        with TraceAnnotation("tune.decide"):
+            changed = True
+            while changed:
+                changed = False
+                for job_id in list(self.resident):
+                    if self.steps_done.get(job_id, 0) >= self.total_steps:
+                        self.monitors[job_id]._exit(
+                            ExitReason.COMPLETED, self.steps_done[job_id])
+                        self._events.append(ProgressEvent(
+                            kind=EventKind.JOB_EXITED, task=self.task_name,
+                            job=job_id, reason=ExitReason.COMPLETED.value,
+                            step=self.steps_done[job_id]))
+                        self._evict_job(job_id)
+                        self._backfill()
+                        changed = True
+            if not self.resident and not self._queue:
+                self._finish()
 
     # ---- results -----------------------------------------------------------
     def _finish(self) -> None:
@@ -974,11 +1000,13 @@ class BatchedExecutor:
         return lc.result()
 
     def _flush(self, lc: TaskLifecycle, steps: int) -> ChunkReport:
-        return ChunkReport(
-            steps_executed=steps, events=lc.drain_events(), phase=lc.phase,
-            remaining_steps_bound=lc.remaining_steps_bound(),
-            wall_time_s=self.backbone.take_wall(), task=lc.task_name,
-            slots_in_use=lc.slots_in_use(), slots_bound=lc.slots_bound(),
-            tokens_executed=self.backbone.take_tokens(),
-            slot_tokens=self.backbone.slot_token_widths(),
-            slot_ranks=self.backbone.slot_rank_vector())
+        with TraceAnnotation("tune.report"):
+            return ChunkReport(
+                steps_executed=steps, events=lc.drain_events(),
+                phase=lc.phase,
+                remaining_steps_bound=lc.remaining_steps_bound(),
+                wall_time_s=self.backbone.take_wall(), task=lc.task_name,
+                slots_in_use=lc.slots_in_use(), slots_bound=lc.slots_bound(),
+                tokens_executed=self.backbone.take_tokens(),
+                slot_tokens=self.backbone.slot_token_widths(),
+                slot_ranks=self.backbone.slot_rank_vector())

@@ -30,6 +30,7 @@ import collections
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.sched.intra_task import MemoryModel
 from repro.serve.pool import AdapterPool
@@ -190,27 +191,28 @@ class ServingFrontend:
     def _fill_lanes(self) -> int:
         """Join queued requests into free lanes, round-robin across
         adapters, re-checking the memory model per join. Returns joins."""
-        joined = 0
-        progress = True
-        while progress:
-            progress = False
-            for adapter_id in list(self._queues):
-                q = self._queues[adapter_id]
-                if not q or adapter_id not in self.pool.resident():
-                    continue
-                r = q[0]
-                slot = self.pool.slot_of(adapter_id)
-                if self.replica.free_lane(slot) is None:
-                    continue
-                if not self._can_join(r):
-                    self.deferred_joins += 1
-                    continue        # re-checked as in-flight work completes
-                q.popleft()
-                ok = self.replica.try_join(r)
-                assert ok
-                self._inflight[r.request_id] = self._request_footprint(r)
-                joined += 1
-                progress = True
+        with TraceAnnotation("serve.fill"):
+            joined = 0
+            progress = True
+            while progress:
+                progress = False
+                for adapter_id in list(self._queues):
+                    q = self._queues[adapter_id]
+                    if not q or adapter_id not in self.pool.resident():
+                        continue
+                    r = q[0]
+                    slot = self.pool.slot_of(adapter_id)
+                    if self.replica.free_lane(slot) is None:
+                        continue
+                    if not self._can_join(r):
+                        self.deferred_joins += 1
+                        continue    # re-checked as in-flight work completes
+                    q.popleft()
+                    ok = self.replica.try_join(r)
+                    assert ok
+                    self._inflight[r.request_id] = self._request_footprint(r)
+                    joined += 1
+                    progress = True
         return joined
 
     def step_continuous(self,

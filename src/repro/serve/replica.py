@@ -41,6 +41,10 @@ independent of WHEN the request joined or which lane it landed on.
 Hot ``publish``/``retire`` on the pool between decode steps is sound in
 both modes — slot isolation — and is exactly what the serving isolation
 tests pin down.
+
+The host work around each launch is marked with ``TraceAnnotation``
+spans named ``serve.*``, on the profiler's clock; their counts are
+numbers the host already holds, so a span never waits on the device.
 """
 from __future__ import annotations
 
@@ -51,6 +55,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core import lora as LORA
@@ -256,52 +261,63 @@ class ServingReplica:
         self._join_step.clear()
         if not pending:
             return
+        with TraceAnnotation("serve.join"):
+            Z, lanes = self.pool.Z, self.lanes
+            block: Dict[Tuple[int, int], ServeRequest] = {}
+            stream: Dict[Tuple[int, int], ServeRequest] = {}
+            for coord, r in pending.items():
+                if self._block_prefill and len(r.prompt) > 1:
+                    block[coord] = r
+                else:
+                    stream[coord] = r
+            if block:
+                P = max(len(r.prompt) for r in block.values())
+                P = min(1 << (P - 1).bit_length(),   # pow-2 padding bucket
+                        self.max_len)                # (cache cap)
+                toks, mask, plens = self._join_batch(block, P)
+                _request_spans(block.values())
+                logits, greedy, self._cache = self._lane_prefill(
+                    self.params, self.pool.lora, self._cache, toks, mask,
+                    plens, self.pool.ranks)
+                self.block_prefills += 1
+                sampled = any(r.temperature > 0 for r in block.values())
+                nxt, rows = _fetch(greedy, logits if sampled else None)
+                for (s, lane), r in block.items():
+                    tok = self._sample(
+                        r, int(nxt[s, lane]),
+                        None if rows is None else rows[s, lane])
+                    r.tokens.append(tok)
+                    self.total_generated += 1
+                    r.fed = len(r.prompt)
+                    r.first_token_t = time.perf_counter()
+                    self._cur[s, lane] = tok
+                    self._activate(s, lane, r)
+            if stream:
+                mask = np.zeros((Z, lanes), bool)
+                for (s, lane) in stream:
+                    mask[s, lane] = True
+                _request_spans(stream.values())
+                self._cache = self._reset_lanes(self._cache,
+                                                jnp.asarray(mask))
+                for (s, lane), r in stream.items():
+                    r.fed = 0
+                    self._cur[s, lane] = r.prompt[0]
+                    self._activate(s, lane, r)
+
+    def _join_batch(self, joiners: Dict[Tuple[int, int], ServeRequest],
+                    P: int) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+        """Joiners' prompts right-padded to ``P`` in their own lanes, the
+        joining-lane mask and the prompt lengths (1 in idle lanes, which
+        read index 0), on the device."""
         Z, lanes = self.pool.Z, self.lanes
-        block: Dict[Tuple[int, int], ServeRequest] = {}
-        stream: Dict[Tuple[int, int], ServeRequest] = {}
-        for coord, r in pending.items():
-            if self._block_prefill and len(r.prompt) > 1:
-                block[coord] = r
-            else:
-                stream[coord] = r
-        if block:
-            P = max(len(r.prompt) for r in block.values())
-            P = min(1 << (P - 1).bit_length(),     # pow-2 padding bucket
-                    self.max_len)                  # (cache cap)
-            toks = np.zeros((Z, lanes, P), np.int32)
-            mask = np.zeros((Z, lanes), bool)
-            plens = np.ones((Z, lanes), np.int32)  # idle rows: index 0
-            for (s, lane), r in block.items():
-                toks[s, lane, :len(r.prompt)] = r.prompt
-                mask[s, lane] = True
-                plens[s, lane] = len(r.prompt)
-            logits, greedy, self._cache = self._lane_prefill(
-                self.params, self.pool.lora, self._cache,
-                jnp.asarray(toks), jnp.asarray(mask), jnp.asarray(plens),
-                self.pool.ranks)
-            self.block_prefills += 1
-            nxt = np.asarray(greedy)
-            rows = np.asarray(logits) if any(
-                r.temperature > 0 for r in block.values()) else None
-            for (s, lane), r in block.items():
-                tok = self._sample(
-                    r, int(nxt[s, lane]),
-                    None if rows is None else rows[s, lane])
-                r.tokens.append(tok)
-                self.total_generated += 1
-                r.fed = len(r.prompt)
-                r.first_token_t = time.perf_counter()
-                self._cur[s, lane] = tok
-                self._activate(s, lane, r)
-        if stream:
-            mask = np.zeros((Z, lanes), bool)
-            for (s, lane) in stream:
-                mask[s, lane] = True
-            self._cache = self._reset_lanes(self._cache, jnp.asarray(mask))
-            for (s, lane), r in stream.items():
-                r.fed = 0
-                self._cur[s, lane] = r.prompt[0]
-                self._activate(s, lane, r)
+        toks = np.zeros((Z, lanes, P), np.int32)
+        mask = np.zeros((Z, lanes), bool)
+        plens = np.ones((Z, lanes), np.int32)
+        for (s, lane), r in joiners.items():
+            toks[s, lane, :len(r.prompt)] = r.prompt
+            mask[s, lane] = True
+            plens[s, lane] = len(r.prompt)
+        return jnp.asarray(toks), jnp.asarray(mask), jnp.asarray(plens)
 
     def _activate(self, slot: int, lane: int, r: ServeRequest) -> None:
         self._lane_req[(slot, lane)] = r
@@ -338,39 +354,39 @@ class ServingReplica:
             if r.done:                      # block prefill covered max_new=1
                 done.append(self._complete(coord, r))
         if fuse:
-            joiners, self._pending_joins = self._pending_joins, {}
-            self._join_step.clear()
-            Z, lanes = self.pool.Z, self.lanes
-            P = max(len(r.prompt) for r in joiners.values())
-            P = min(1 << (P - 1).bit_length(), self.max_len)
-            toks = np.zeros((Z, lanes, P), np.int32)
-            mask = np.zeros((Z, lanes), bool)
-            plens = np.ones((Z, lanes), np.int32)
-            for (s, lane), r in joiners.items():
-                toks[s, lane, :len(r.prompt)] = r.prompt
-                mask[s, lane] = True
-                plens[s, lane] = len(r.prompt)
+            with TraceAnnotation("serve.join"):
+                joiners, self._pending_joins = self._pending_joins, {}
+                self._join_step.clear()
+                P = max(len(r.prompt) for r in joiners.values())
+                P = min(1 << (P - 1).bit_length(), self.max_len)
+                toks, mask, plens = self._join_batch(joiners, P)
             if on_step is not None:
                 on_step(self.total_decode_steps)
             if self._active_dev is None:
                 self._active_dev = jnp.asarray(self._active)
-            p_greedy, logits, greedy, self._cache = self._join_decode(
-                self.params, self.pool.lora, self._cache,
-                jnp.asarray(toks), jnp.asarray(mask), jnp.asarray(plens),
-                jnp.asarray(self._cur), self._active_dev, self.pool.ranks)
+            _request_spans(joiners.values())
+            with TraceAnnotation(
+                    "serve.dispatch",
+                    active_lanes=len(self._lane_req) + len(joiners),
+                    lanes=self._active.size):
+                p_greedy, logits, greedy, self._cache = self._join_decode(
+                    self.params, self.pool.lora, self._cache, toks, mask,
+                    plens, jnp.asarray(self._cur), self._active_dev,
+                    self.pool.ranks)
             self.block_prefills += 1
-            p_nxt = np.asarray(p_greedy)
+            p_nxt, _ = _fetch(p_greedy)
             now = time.perf_counter()
-            for (s, lane), r in joiners.items():
-                tok = int(p_nxt[s, lane])
-                r.tokens.append(tok)
-                self.total_generated += 1
-                r.fed = len(r.prompt)
-                r.first_token_t = now
-                self._cur[s, lane] = tok
-                self._activate(s, lane, r)
-                if r.done:      # max_new == 1: prefill covered it fully
-                    done.append(self._complete((s, lane), r))
+            with TraceAnnotation("serve.emit"):
+                for (s, lane), r in joiners.items():
+                    tok = int(p_nxt[s, lane])
+                    r.tokens.append(tok)
+                    self.total_generated += 1
+                    r.fed = len(r.prompt)
+                    r.first_token_t = now
+                    self._cur[s, lane] = tok
+                    self._activate(s, lane, r)
+                    if r.done:      # max_new == 1: prefill covered it fully
+                        done.append(self._complete((s, lane), r))
         else:
             if not self._lane_req:
                 self.total_wall_s += time.perf_counter() - t0
@@ -379,33 +395,35 @@ class ServingReplica:
                 on_step(self.total_decode_steps)
             if self._active_dev is None:  # re-upload only on lane churn
                 self._active_dev = jnp.asarray(self._active)
-            logits, greedy, self._cache = self._decode_lanes(
-                self.params, self.pool.lora, self._cache,
-                jnp.asarray(self._cur), self._active_dev,
-                self.pool.ranks)
-        nxt = np.asarray(greedy)
-        rows = None
-        if record_logits or any(r.temperature > 0
-                                for r in self._lane_req.values()):
-            rows = np.asarray(logits)
+            with TraceAnnotation("serve.dispatch",
+                                 active_lanes=len(self._lane_req),
+                                 lanes=self._active.size):
+                logits, greedy, self._cache = self._decode_lanes(
+                    self.params, self.pool.lora, self._cache,
+                    jnp.asarray(self._cur), self._active_dev,
+                    self.pool.ranks)
+        keep = record_logits or any(r.temperature > 0
+                                    for r in self._lane_req.values())
+        nxt, rows = _fetch(greedy, logits if keep else None)
         if record_logits:
             self.step_logits.append((self.total_decode_steps, rows))
         generated = 0
-        for (s, lane), r in list(self._lane_req.items()):
-            P = len(r.prompt)
-            r.fed += 1
-            if r.fed < P:                   # still consuming its prompt
-                self._cur[s, lane] = r.prompt[r.fed]
-                continue
-            tok = self._sample(r, int(nxt[s, lane]),
-                               None if rows is None else rows[s, lane])
-            if r.first_token_t is None:
-                r.first_token_t = time.perf_counter()
-            r.tokens.append(tok)
-            generated += 1
-            self._cur[s, lane] = tok
-            if r.done:
-                done.append(self._complete((s, lane), r))
+        with TraceAnnotation("serve.emit"):
+            for (s, lane), r in list(self._lane_req.items()):
+                P = len(r.prompt)
+                r.fed += 1
+                if r.fed < P:                   # still consuming its prompt
+                    self._cur[s, lane] = r.prompt[r.fed]
+                    continue
+                tok = self._sample(r, int(nxt[s, lane]),
+                                   None if rows is None else rows[s, lane])
+                if r.first_token_t is None:
+                    r.first_token_t = time.perf_counter()
+                r.tokens.append(tok)
+                generated += 1
+                self._cur[s, lane] = tok
+                if r.done:
+                    done.append(self._complete((s, lane), r))
         self.total_decode_steps += 1
         self.total_generated += generated
         self.total_wall_s += time.perf_counter() - t0
@@ -480,26 +498,29 @@ class ServingReplica:
         generated = 0
         while True:
             if logits is not None:
-                nxt = np.asarray(greedy)
+                nxt, rows = _fetch(greedy, logits if record_logits else None)
                 if record_logits:
-                    logits_log.append((t, np.asarray(logits)))
-                for (s, lane), r in lane_req.items():
-                    P = len(r.prompt)
-                    if t < P - 1:
-                        cur[s, lane] = r.prompt[t + 1]
-                    else:
-                        tok = int(nxt[s, lane])
-                        if not r.done:
-                            r.tokens.append(tok)
-                            generated += 1
-                        cur[s, lane] = tok
+                    logits_log.append((t, rows))
+                with TraceAnnotation("serve.emit"):
+                    for (s, lane), r in lane_req.items():
+                        P = len(r.prompt)
+                        if t < P - 1:
+                            cur[s, lane] = r.prompt[t + 1]
+                        else:
+                            tok = int(nxt[s, lane])
+                            if not r.done:
+                                r.tokens.append(tok)
+                                generated += 1
+                            cur[s, lane] = tok
                 if all(r.done for r in lane_req.values()):
                     break
             if on_step is not None:
                 on_step(steps)
-            logits, greedy, cache = self._decode(self.params, pool.lora,
-                                                 cache, jnp.asarray(cur),
-                                                 pool.ranks)
+            with TraceAnnotation("serve.dispatch",
+                                 active_lanes=len(lane_req), lanes=cur.size):
+                logits, greedy, cache = self._decode(self.params, pool.lora,
+                                                     cache, jnp.asarray(cur),
+                                                     pool.ranks)
             steps += 1
             t += 1
         jax.block_until_ready(logits)
@@ -515,3 +536,25 @@ class ServingReplica:
     @property
     def aggregate_tok_s(self) -> float:
         return self.total_generated / max(self.total_wall_s, 1e-9)
+
+
+def _request_spans(joiners) -> None:
+    """One short ``serve.request`` event per joiner as its prefill is
+    launched (block prefill) or its lane is reset to stream its prompt:
+    whole milliseconds on the host clock from submit to lane
+    (``queue_ms``) and from lane to this launch (``join_wait_ms``)."""
+    launch = time.perf_counter()
+    for r in joiners:
+        with TraceAnnotation("serve.request",
+                             queue_ms=round(1e3 * (r.join_t - r.submit_t)),
+                             join_wait_ms=round(1e3 * (launch - r.join_t))):
+            pass
+
+
+def _fetch(greedy: jax.Array, logits: Optional[jax.Array] = None
+           ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+    """The step's argmax tokens (and its logits, where given) on the
+    host."""
+    with TraceAnnotation("serve.token_fetch"):
+        return (np.asarray(greedy),
+                None if logits is None else np.asarray(logits))
