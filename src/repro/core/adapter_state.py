@@ -16,6 +16,16 @@ preemption and cross-replica migration invisible to the loss trajectory:
 a migrated job's subsequent losses are bitwise identical to never moving
 (tests/test_lora_isolation.py).
 
+Each slot write is one jitted program: a slot index and a rank are
+traced scalars, so one compilation serves every slot and rank of a sweep.
+The optimizer state, two thirds of the slot state's bytes, is donated
+and written in place; the adapter tree is not donated, so an adapter tree
+a caller took before the write stays readable (bench/faults.py's refill
+faults put it back), at the cost of one on-device copy of it a write.
+Admission and eviction share a program (eviction is an admission with
+``live`` 0 and rank 0); a restore has its own, fed from the snapshot's
+host arrays.
+
 Each slot write and snapshot is a ``TraceAnnotation`` span (``tune.admit``,
 ``tune.restore``, ``tune.evict``, ``tune.snapshot``) on the profiler's
 clock; a snapshot's span counts the bytes it copies to the host.
@@ -23,7 +33,8 @@ clock; a snapshot's span counts the bytes it copies to the host.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+import functools
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -57,9 +68,69 @@ def _x_slot(tree: Dict, slot: int) -> Dict:
     return jax.tree_util.tree_map(lambda x: np.asarray(x[:, slot]), tree)
 
 
-def _i_slot(tree: Dict, slot: int, sub: Dict) -> Dict:
+def _slot_hp(tc: TrainConfig) -> adamw.SlotHParams:
+    """A job's hyperparameters as one slot's row of ``SlotHParams``."""
+    return adamw.SlotHParams(
+        lr=np.float32(tc.learning_rate), wd=np.float32(tc.weight_decay),
+        beta1=np.float32(tc.beta1), beta2=np.float32(tc.beta2),
+        grad_clip=np.float32(tc.grad_clip))
+
+
+# eviction keeps the slot's hyperparameters: its row argument goes unread
+_KEEP_HP = adamw.SlotHParams(
+    **dict.fromkeys(adamw.SlotHParams._fields, np.float32(0.0)))
+
+
+def _put_hp(hps: adamw.SlotHParams, slot, row: adamw.SlotHParams,
+            live=1) -> adamw.SlotHParams:
     return jax.tree_util.tree_map(
-        lambda full, one: full.at[:, slot].set(jnp.asarray(one)), tree, sub)
+        lambda v, h: v.at[slot].set(jnp.where(live, h, v[slot])), hps, row)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=(4,))
+def _admit_program(cfg: ModelConfig, target_shapes: Tuple, reset_slot,
+                   lora: Dict, opt: adamw.AdamWState, small: Tuple,
+                   key: jax.Array, slot: jnp.ndarray, rank: jnp.ndarray,
+                   live: jnp.ndarray, hp: adamw.SlotHParams):
+    """Admit (``live`` 1) or evict (``live`` 0, ``rank`` 0) one slot.
+
+    Admission writes ``init_lora_tree(key, cfg, 1, [rank], ...)``'s
+    adapter, bit for bit, and the job's hyperparameters ``hp``; eviction
+    writes +0.0 (a ``where``, not a product with a zero mask, which would
+    leave -0.0) and keeps the slot's hyperparameters. Both zero the slot's
+    moments and step count through ``reset_slot`` and set its rank and
+    active flag. ``reset_slot`` is the caller's ``adamw.reset_slot`` as it
+    stands at the call: a static argument, so a replaced reset is traced
+    anew and not served from the cache. ``small`` is (ranks, active, hp
+    vectors)."""
+    ranks, active, hps = small
+    fresh = LORA.init_lora_tree(key, cfg, 1, rank[None], dict(target_shapes))
+    lora = jax.tree_util.tree_map(
+        lambda full, one: full.at[:, slot].set(
+            jnp.where(live, one[:, 0], 0.0)), lora, fresh)
+    return lora, reset_slot(opt, slot), (
+        ranks.at[slot].set(rank), active.at[slot].set(live),
+        _put_hp(hps, slot, hp, live))
+
+
+@functools.partial(jax.jit, donate_argnums=(1,))
+def _restore_program(lora: Dict, opt: adamw.AdamWState, small: Tuple,
+                     slot: jnp.ndarray, snap_lora: Dict, mu: Dict, nu: Dict,
+                     count: jnp.ndarray, rank: jnp.ndarray,
+                     hp: adamw.SlotHParams):
+    """Write a snapshot's adapter, moments, step count and rank, and the
+    job's hyperparameters ``hp``, into one slot, and activate it."""
+    ranks, active, hps = small
+
+    def put(tree, sub):
+        return jax.tree_util.tree_map(
+            lambda full, one: full.at[:, slot].set(one), tree, sub)
+
+    opt = adamw.AdamWState(put(opt.mu, mu), put(opt.nu, nu),
+                           opt.count.at[slot].set(count))
+    return put(lora, snap_lora), opt, (ranks.at[slot].set(rank),
+                                       active.at[slot].set(1),
+                                       _put_hp(hps, slot, hp))
 
 
 class SlotManager:
@@ -95,6 +166,14 @@ class SlotManager:
         # host mirror of ``ranks``: the per-step rank-local dispatch and
         # the §A.3 rank accounting must not sync a device array
         self.slot_rank: List[int] = [0] * Z
+        self._shapes = tuple(target_shapes.items())   # a static argument
+        self._evict_key = key
+
+    def _small(self) -> Tuple:
+        return self.ranks, self.active, self.hp
+
+    def _set_state(self, out) -> None:
+        self.lora, self.opt_state, (self.ranks, self.active, self.hp) = out
 
     # ---- admission ---------------------------------------------------------
     def admit(self, slot: int, job_id: str, tc: TrainConfig,
@@ -105,16 +184,10 @@ class SlotManager:
         assert self.slot_jobs[slot] is None, f"slot {slot} occupied"
         rank = min(tc.lora_rank, self.cfg.lora.r_max)
         with TraceAnnotation("tune.admit"):
-            one = LORA.init_lora_tree(
-                key, self.cfg, 1, jnp.array([rank]), self.target_shapes)
-            sub = jax.tree_util.tree_map(lambda x: x[:, 0], one)
-            self.lora = _i_slot(self.lora, slot, sub)
-            self.opt_state = adamw.reset_slot(self.opt_state, slot)
-            self.ranks = self.ranks.at[slot].set(rank)
-            self.active = self.active.at[slot].set(1)
-        self.hp = self.hp.replace_slot(
-            slot, lr=tc.learning_rate, wd=tc.weight_decay,
-            beta1=tc.beta1, beta2=tc.beta2, grad_clip=tc.grad_clip)
+            self._set_state(_admit_program(
+                self.cfg, self._shapes, adamw.reset_slot, self.lora,
+                self.opt_state, self._small(), key,
+                np.int32(slot), np.int32(rank), np.int32(1), _slot_hp(tc)))
         self.slot_jobs[slot] = job_id
         self.slot_tasks[slot] = task
         self.slot_b[slot] = b or tc.per_adapter_batch
@@ -127,16 +200,10 @@ class SlotManager:
         including its slot width)."""
         assert self.slot_jobs[slot] is None, f"slot {slot} occupied"
         with TraceAnnotation("tune.restore"):
-            self.lora = _i_slot(self.lora, slot, snap.lora)
-            mu = _i_slot(self.opt_state.mu, slot, snap.mu)
-            nu = _i_slot(self.opt_state.nu, slot, snap.nu)
-            cnt = self.opt_state.count.at[slot].set(snap.count)
-            self.opt_state = adamw.AdamWState(mu, nu, cnt)
-            self.ranks = self.ranks.at[slot].set(snap.rank)
-            self.active = self.active.at[slot].set(1)
-        self.hp = self.hp.replace_slot(
-            slot, lr=tc.learning_rate, wd=tc.weight_decay,
-            beta1=tc.beta1, beta2=tc.beta2, grad_clip=tc.grad_clip)
+            self._set_state(_restore_program(
+                self.lora, self.opt_state, self._small(), np.int32(slot),
+                snap.lora, snap.mu, snap.nu, np.int32(snap.count),
+                np.int32(snap.rank), _slot_hp(tc)))
         self.slot_jobs[slot] = snap.job_id
         self.slot_tasks[slot] = task
         self.slot_b[slot] = snap.per_adapter_batch or tc.per_adapter_batch
@@ -167,10 +234,10 @@ class SlotManager:
         """Drop a job: zero params + moments, deactivate (paper §5.2:
         'evicted adapters' parameters and optimizer states are discarded')."""
         with TraceAnnotation("tune.evict"):
-            self.lora = LORA.zero_slot(self.lora, slot)
-            self.opt_state = adamw.reset_slot(self.opt_state, slot)
-            self.active = self.active.at[slot].set(0)
-            self.ranks = self.ranks.at[slot].set(0)
+            self._set_state(_admit_program(
+                self.cfg, self._shapes, adamw.reset_slot, self.lora,
+                self.opt_state, self._small(), self._evict_key,
+                np.int32(slot), np.int32(0), np.int32(0), _KEEP_HP))
         self.slot_jobs[slot] = None
         self.slot_tasks[slot] = None
         self.slot_b[slot] = 0

@@ -22,6 +22,7 @@ validated in interpret mode on CPU and targeted at TPU VMEM/MXU.
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 from typing import Dict, Optional, Tuple
 
@@ -223,8 +224,13 @@ def rank_mask(ranks: jnp.ndarray, r_max: int) -> jnp.ndarray:
 
 def init_slot_lora(key: jax.Array, d_in: int, d_out: int, r_max: int, Z: int,
                    ranks: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """LoRA init: A ~ N(0, 1/r_max) (rank-masked), B = 0. fp32 master."""
-    A = jax.random.normal(key, (Z, d_in, r_max), jnp.float32)
+    """LoRA init: A ~ N(0, 1/r_max) (rank-masked), B = 0. fp32 master.
+
+    The barrier keeps XLA from folding the scale into the normal's own
+    constants inside a larger program, which would round A differently
+    from the eager init (for an r_max whose scale is not a power of 2)."""
+    A = jax.lax.optimization_barrier(
+        jax.random.normal(key, (Z, d_in, r_max), jnp.float32))
     A = A * (r_max ** -0.5) * rank_mask(ranks, r_max)[:, None, :]
     B = jnp.zeros((Z, r_max, d_out), jnp.float32)
     return A, B
@@ -238,22 +244,22 @@ def init_lora_tree(key: jax.Array, cfg: ModelConfig, Z: int,
 
     Only targets present in ``target_shapes`` AND ``cfg.lora.targets`` get
     adapters (paper: all attention + MLP projections; per-family sets differ).
+    Layer l of the i-th target draws from split key ``i * L + l``; one
+    ``vmap`` over a target's L layer keys gives the same bits as
+    ``init_slot_lora`` layer by layer, in one op per target instead of L.
+    Traceable with ``ranks`` traced (``SlotManager``'s admission program).
     """
     L = num_layers if num_layers is not None else cfg.num_layers
     r = cfg.lora.r_max
     tree: Dict[str, Dict[str, jnp.ndarray]] = {}
     targets = [t for t in cfg.lora.targets if t in target_shapes]
     keys = jax.random.split(key, max(len(targets) * L, 1))
-    i = 0
-    for t in targets:
+    for i, t in enumerate(targets):
         d_in, d_out = target_shapes[t]
-        As, Bs = [], []
-        for _ in range(L):
-            A, B = init_slot_lora(keys[i], d_in, d_out, r, Z, ranks)
-            As.append(A)
-            Bs.append(B)
-            i += 1
-        tree[t] = {"A": jnp.stack(As), "B": jnp.stack(Bs)}
+        A, B = jax.vmap(functools.partial(
+            init_slot_lora, d_in=d_in, d_out=d_out, r_max=r, Z=Z,
+            ranks=ranks))(keys[i * L:(i + 1) * L])
+        tree[t] = {"A": A, "B": B}
     return tree
 
 

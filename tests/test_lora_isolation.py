@@ -8,6 +8,8 @@ lifted one level, what makes CROSS-TASK co-location sound: two different
 tasks' slots on one shared executor train exactly as each task would
 alone (the executor-level tests at the bottom)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,12 +17,14 @@ import pytest
 
 from repro.configs.base import TrainConfig
 from repro.core import lora as LORA
+from repro.core.adapter_state import SlotManager
 from repro.core.early_exit import EarlyExitConfig
 from repro.core.executor import (SharedBackboneExecutor, TaskLifecycle,
                                  run_colocated)
 from repro.core.losses import sft_loss
 from repro.data.synthetic import SlotBatcher, make_task_dataset
 from repro.models import model as M
+from repro.optim import adamw
 from tests.conftest import reduced_f32
 
 
@@ -606,3 +610,250 @@ def test_migration_across_replicas_bitwise_equal(exec_env):
     # the bystanders were untouched too
     assert np.isfinite(C.result().best_val)
     assert np.isfinite(B.result().best_val)
+
+
+# ---------------------------------------------------------------------------
+# Slot writes: admission, eviction and restore, each one donated program
+# ---------------------------------------------------------------------------
+
+def _init_loop(key, cfg, Z, ranks, shapes):
+    """``init_lora_tree`` as a plain loop: one ``init_slot_lora`` a layer."""
+    L, r = cfg.num_layers, cfg.lora.r_max
+    targets = [t for t in cfg.lora.targets if t in shapes]
+    keys = jax.random.split(key, len(targets) * L)
+    tree = {}
+    for i, t in enumerate(targets):
+        pairs = [LORA.init_slot_lora(keys[i * L + l], *shapes[t], r, Z, ranks)
+                 for l in range(L)]
+        tree[t] = {"A": jnp.stack([a for a, _ in pairs]),
+                   "B": jnp.stack([b for _, b in pairs])}
+    return tree
+
+
+def _bytes(tree):
+    return [np.asarray(x).tobytes() for x in jax.tree_util.tree_leaves(tree)]
+
+
+def _state(sm):
+    """Every device array a SlotManager holds, copied to the host."""
+    return jax.tree_util.tree_map(
+        np.asarray, (sm.lora, sm.opt_state, sm.ranks, sm.active, sm.hp))
+
+
+def _held(cfg, Z=3):
+    """A manager whose every slot holds nonzero adapters, moments and step
+    counts, as after training: a slot write must replace them all."""
+    sm = SlotManager(cfg, Z, M.target_shapes(cfg), jax.random.PRNGKey(7))
+    k = iter(jax.random.split(jax.random.PRNGKey(8), 64))
+
+    def noisy(tree):
+        return jax.tree_util.tree_map(
+            lambda x: x + jax.random.normal(next(k), x.shape), tree)
+
+    sm.lora = noisy(sm.lora)
+    sm.opt_state = adamw.AdamWState(noisy(sm.opt_state.mu),
+                                    noisy(sm.opt_state.nu),
+                                    jnp.full((Z,), 5, jnp.int32))
+    sm.ranks = jnp.full((Z,), 3, jnp.int32)
+    sm.active = jnp.ones((Z,), jnp.int32)
+    return sm
+
+
+_TC = dict(learning_rate=3e-3, weight_decay=0.05, beta1=0.8, beta2=0.95,
+           grad_clip=0.5)
+
+
+@pytest.mark.parametrize("Z,ranks", [(1, [3]), (3, [2, 8, 0]), (2, [8, 8])])
+def test_init_lora_tree_matches_per_layer_loop(exec_env, Z, ranks):
+    """The vmapped init draws each layer's adapter from the same key as the
+    per-layer loop, bit for bit, padded rank columns and zero ranks
+    included."""
+    cfg = exec_env[0]
+    key = jax.random.PRNGKey(21)
+    shapes = M.target_shapes(cfg)
+    got = LORA.init_lora_tree(key, cfg, Z, jnp.asarray(ranks), shapes)
+    ref = _init_loop(key, cfg, Z, jnp.asarray(ranks), shapes)
+    assert list(got) == list(ref)
+    assert _bytes(got) == _bytes(ref)
+
+
+@pytest.mark.parametrize("slot,rank", [(0, 3), (2, 8), (1, 8)])
+def test_admit_writes_slot_like_eager_reference(exec_env, slot, rank):
+    """An admission leaves every array of the manager bitwise as the eager
+    writes would: ``init_lora_tree``'s adapter put in with ``.at[:, slot]
+    .set``, zeroed moments and count, the job's rank, active flag and
+    hyperparameters; the other slots as they were. Ranks below and at
+    r_max (8)."""
+    cfg = exec_env[0]
+    sm = _held(cfg)
+    key = jax.random.PRNGKey(31)
+    tc = TrainConfig(lora_rank=rank, **_TC)
+    one = LORA.init_lora_tree(key, cfg, 1, jnp.asarray([rank]),
+                              M.target_shapes(cfg))
+    zero = functools.partial(jax.tree_util.tree_map,
+                             lambda x: x.at[:, slot].set(0.0))
+    hp = sm.hp
+    ref = jax.tree_util.tree_map(np.asarray, (
+        jax.tree_util.tree_map(lambda x, y: x.at[:, slot].set(y[:, 0]),
+                               sm.lora, one),
+        adamw.AdamWState(zero(sm.opt_state.mu), zero(sm.opt_state.nu),
+                         sm.opt_state.count.at[slot].set(0)),
+        sm.ranks.at[slot].set(rank), sm.active.at[slot].set(1),
+        adamw.SlotHParams(
+            hp.lr.at[slot].set(tc.learning_rate),
+            hp.wd.at[slot].set(tc.weight_decay),
+            hp.beta1.at[slot].set(tc.beta1), hp.beta2.at[slot].set(tc.beta2),
+            hp.grad_clip.at[slot].set(tc.grad_clip))))
+    sm.slot_jobs = [None] * sm.Z
+    sm.admit(slot, "job", tc, key)
+    assert _bytes(_state(sm)) == _bytes(ref)
+    assert sm.slot_rank[slot] == rank and sm.slot_jobs[slot] == "job"
+
+
+@pytest.mark.parametrize("slot,rank", [(0, 3), (2, 8)])
+def test_evict_writes_positive_zeros(exec_env, slot, rank):
+    """An eviction leaves every leaf of the slot exactly +0.0 (by bytes:
+    no -0.0 from a rank mask), its count, rank and active flag 0, its
+    hyperparameters and every other slot's bytes as they were."""
+    cfg = exec_env[0]
+    sm = _held(cfg)
+    sm.slot_jobs = [None] * sm.Z
+    sm.admit(slot, "job", TrainConfig(lora_rank=rank, **_TC),
+             jax.random.PRNGKey(41))
+    # the slot trains on: its adapter and moments move off the fresh ones
+    sm.lora = jax.tree_util.tree_map(lambda x: x - 0.25, sm.lora)
+    before = _state(sm)
+    sm.evict(slot)
+    after = _state(sm)
+    for b, a in zip(
+            jax.tree_util.tree_leaves((before[0], before[1].mu,
+                                       before[1].nu)),
+            jax.tree_util.tree_leaves((after[0], after[1].mu, after[1].nu))):
+        assert a[:, slot].tobytes() == np.zeros_like(a[:, slot]).tobytes()
+        others = [z for z in range(sm.Z) if z != slot]
+        assert a[:, others].tobytes() == b[:, others].tobytes()
+    for b, a in zip((before[1].count, before[2], before[3]),
+                    (after[1].count, after[2], after[3])):
+        assert a[slot] == 0
+        assert np.delete(a, slot).tobytes() == np.delete(b, slot).tobytes()
+    assert _bytes(after[4]) == _bytes(before[4])
+    assert sm.slot_jobs[slot] is None and sm.slot_rank[slot] == 0
+
+
+@pytest.mark.parametrize("src,dst", [(0, 2), (2, 1)])
+def test_snapshot_restore_onto_another_slot_bitwise(exec_env, src, dst):
+    """A snapshot restored onto another slot carries the adapter, moments,
+    step count and rank over bit for bit, with the job's hyperparameters;
+    the slots in between keep their bytes."""
+    cfg = exec_env[0]
+    sm = _held(cfg)
+    sm.slot_jobs = [None] * sm.Z
+    tc = TrainConfig(lora_rank=3, **_TC)
+    sm.admit(src, "job", tc, jax.random.PRNGKey(51))
+    sm.lora = jax.tree_util.tree_map(lambda x: x * 1.5 + 0.125, sm.lora)
+    sm.opt_state = adamw.AdamWState(
+        jax.tree_util.tree_map(lambda x: x + 0.5, sm.opt_state.mu),
+        jax.tree_util.tree_map(lambda x: x + 2.0, sm.opt_state.nu),
+        sm.opt_state.count.at[src].set(9))
+    snap = sm.snapshot(src)
+    sm.evict(src)
+    before = _state(sm)
+    sm.restore(dst, snap, tc)
+    again = sm.snapshot(dst)
+    assert _bytes((again.lora, again.mu, again.nu)) == \
+        _bytes((snap.lora, snap.mu, snap.nu))
+    assert (again.count, again.rank, again.job_id) == (9, 3, "job")
+    after = _state(sm)
+    assert after[3][dst] == 1
+    np.testing.assert_array_equal(
+        [h[dst] for h in after[4]],
+        np.float32([tc.learning_rate, tc.weight_decay, tc.beta1, tc.beta2,
+                    tc.grad_clip]))
+    keep = [z for z in range(sm.Z) if z != dst]
+    for b, a in zip(jax.tree_util.tree_leaves(before),
+                    jax.tree_util.tree_leaves(after)):
+        if a.ndim >= 2:
+            assert a[:, keep].tobytes() == b[:, keep].tobytes()
+        else:
+            assert a[keep].tobytes() == b[keep].tobytes()
+
+
+def test_slot_writes_compile_once(exec_env):
+    """Once a manager has admitted and restored a job, admissions into
+    other slots at other ranks, evictions and restores elsewhere lower no
+    new program: slot and rank are traced, not static."""
+    cfg = exec_env[0]
+    sm = SlotManager(cfg, 3, M.target_shapes(cfg), jax.random.PRNGKey(7))
+    keys = jax.random.split(jax.random.PRNGKey(61), 3)
+    sm.admit(0, "a", TrainConfig(lora_rank=8, **_TC), keys[0])
+    snap = sm.snapshot(0)
+    sm.evict(0)
+    sm.restore(1, snap, TrainConfig(lora_rank=8, **_TC))
+    snap = sm.snapshot(1)
+    jax.block_until_ready(sm.lora)
+    lowered = []
+
+    def listen(event, duration, **kw):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        sm.admit(2, "b", TrainConfig(lora_rank=2, **_TC), keys[1])
+        sm.evict(1)
+        sm.admit(1, "c", TrainConfig(lora_rank=5, **_TC), keys[2])
+        sm.evict(2)
+        sm.restore(2, snap, TrainConfig(lora_rank=8, **_TC))
+        jax.block_until_ready(sm.lora)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert lowered == []
+    assert sm.slot_rank == [0, 5, 8] and list(np.asarray(sm.ranks)) == [0, 5, 8]
+
+
+@pytest.mark.parametrize("write", ["admit", "evict", "restore"])
+def test_slot_writes_leave_callers_adapters_readable(exec_env, write):
+    """A slot write does not consume the adapter tree it replaces: an
+    adapter tree taken before the write still reads as it did (only the
+    optimizer state is donated)."""
+    cfg = exec_env[0]
+    sm = _held(cfg)
+    sm.slot_jobs = [None] * sm.Z
+    tc = TrainConfig(lora_rank=3, **_TC)
+    sm.admit(0, "job", tc, jax.random.PRNGKey(71))
+    snap = sm.snapshot(0)
+    if write != "admit":
+        sm.evict(0)
+    taken = sm.lora
+    before = _bytes(taken)
+    if write == "admit":
+        sm.admit(1, "next", tc, jax.random.PRNGKey(72))
+    elif write == "evict":
+        sm.admit(1, "next", tc, jax.random.PRNGKey(72))
+        taken, before = sm.lora, _bytes(sm.lora)
+        sm.evict(1)
+    else:
+        sm.restore(2, snap, tc)
+    assert _bytes(taken) == before
+    assert _bytes(sm.lora) != before
+
+
+def test_admission_follows_replaced_optimizer_reset(exec_env, monkeypatch):
+    """The admission program runs ``adamw.reset_slot`` as it stands at the
+    call, not as it stood when the program was first traced: replaced by
+    one that keeps the state, an admission into a trained slot keeps its
+    moments and count; put back, the next admission zeroes them."""
+    cfg = exec_env[0]
+    sm = _held(cfg)
+    sm.slot_jobs = [None] * sm.Z
+    tc = TrainConfig(lora_rank=3, **_TC)
+    sm.admit(0, "first", tc, jax.random.PRNGKey(81))   # traced as it is
+    kept = _bytes((sm.opt_state.mu, sm.opt_state.nu))
+    monkeypatch.setattr(adamw, "reset_slot", lambda state, slot: state)
+    sm.admit(1, "stale", tc, jax.random.PRNGKey(82))
+    assert _bytes((sm.opt_state.mu, sm.opt_state.nu)) == kept
+    assert int(sm.opt_state.count[1]) == 5
+    monkeypatch.undo()
+    sm.evict(1)
+    mu = np.asarray(jax.tree_util.tree_leaves(sm.opt_state.mu)[0])
+    assert not mu[:, 1].any() and int(sm.opt_state.count[1]) == 0

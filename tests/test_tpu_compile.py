@@ -139,3 +139,49 @@ def test_stablelm_train_step_fits_at_picked_slots(one_chip):
     state = sum(x.size * x.dtype.itemsize
                 for x in jax.tree_util.tree_leaves((lora, opt)))
     assert mem.alias_size_in_bytes >= 0.99 * state
+
+
+@pytest.mark.parametrize("write", ["admit", "restore"])
+def test_stablelm_slot_writes_in_place(one_chip, write):
+    """``SlotManager``'s admission (and eviction) program and its restore
+    program at stablelm-3b widths, Z=2: the donated optimizer state aliases
+    the output, so a slot write copies no moment leaf."""
+    from repro.core import adapter_state as AS
+    from repro.core import lora as LORA
+    from repro.models import model as M
+    from repro.optim import adamw
+
+    cfg, z = STABLELM, 2
+    shapes = M.target_shapes(cfg)
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+
+    def adapters(n):
+        return LORA.init_lora_tree(jax.random.PRNGKey(0), cfg, n,
+                                   jnp.zeros((n,), jnp.int32), shapes)
+
+    lora = sds(jax.eval_shape(lambda: adapters(z)))
+    opt = sds(jax.eval_shape(lambda: adamw.init_state(adapters(z), z)))
+    vec = _sds(one_chip, (z,), jnp.int32)
+    small = (vec, vec, sds(jax.eval_shape(
+        lambda: adamw.SlotHParams.broadcast(z))))
+    scalar = _sds(one_chip, (), jnp.int32)
+    hp = adamw.SlotHParams(**dict.fromkeys(
+        adamw.SlotHParams._fields, _sds(one_chip, (), jnp.float32)))
+    if write == "admit":
+        key = _sds(one_chip, (2,), jnp.uint32)
+        lowered = AS._admit_program.lower(
+            cfg, tuple(shapes.items()), adamw.reset_slot, lora, opt, small,
+            key, scalar, scalar, scalar, hp)
+    else:
+        one = jax.tree_util.tree_map(
+            lambda x: _sds(one_chip, x.shape[:1] + x.shape[2:], x.dtype),
+            lora)
+        lowered = AS._restore_program.lower(lora, opt, small, scalar, one,
+                                            one, one, scalar, scalar, hp)
+    mem = lowered.compile().memory_analysis()
+    state = sum(x.size * x.dtype.itemsize
+                for x in jax.tree_util.tree_leaves((opt.mu, opt.nu)))
+    assert mem.alias_size_in_bytes >= 0.99 * state
